@@ -15,10 +15,11 @@ Registered as a slow suite: the default ``--quick`` smoke skips it; the
 from __future__ import annotations
 
 import json
-import os
 import subprocess
 import sys
 from pathlib import Path
+
+from .util import cpu_child_env
 
 RESULTS_PATH = (
     Path(__file__).resolve().parents[1] / "experiments" / "mesh_bench_results.json"
@@ -94,9 +95,7 @@ def run(full: bool = False, quick: bool = False):
         n_grid, scale, repeats = 32, 11, 4
     else:
         n_grid, scale, repeats = 24, 10, 3
-    env = dict(os.environ)
-    src = str(Path(__file__).resolve().parents[1] / "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    env = cpu_child_env()
     RESULTS_PATH.parent.mkdir(parents=True, exist_ok=True)
     proc = subprocess.run(
         [sys.executable, "-c", SCRIPT,
@@ -108,7 +107,9 @@ def run(full: bool = False, quick: bool = False):
             f"mesh-8dev subprocess failed (rc={proc.returncode}):\n"
             f"stdout={proc.stdout}\nstderr={proc.stderr}"
         )
-    rows = json.loads(RESULTS_PATH.read_text())
+    rows = [
+        {**row, "platform": "cpu"} for row in json.loads(RESULTS_PATH.read_text())
+    ]
     cache_row = next(r for r in rows if r["case"] == "_cache")
     service_row = next(r for r in rows if r["case"] == "_service")
     if not cache_row["hits"] > 0:

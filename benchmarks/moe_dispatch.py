@@ -7,18 +7,16 @@ via ``repro.models.moe.dispatch_from_strategy`` (the engine mapping)."""
 from __future__ import annotations
 
 import json
-import os
 import subprocess
 import sys
 
-from .util import emit
+from .util import cpu_child_env, emit
 
 SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax, jax.numpy as jnp
-from repro.compat import make_mesh
 from repro.core.strategies import Comm, MigratoryStrategy
 from repro.models.config import ModelConfig
 from repro.models.layers import Ctx
@@ -31,7 +29,9 @@ cfg = ModelConfig(
     num_kv_heads=8, d_ff=1024, vocab_size=1024, num_experts=16,
     experts_per_token=2, moe_d_ff=1024, dtype="float32", remat=False,
 )
-mesh = make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh(
+    (4, 2), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2
+)
 rules = make_rules(mesh, num_experts=cfg.num_experts, num_heads=8, num_kv_heads=8)
 ctx = Ctx(cfg=cfg, mesh=mesh, rules=rules)
 params = moe_params(cfg, jax.random.PRNGKey(0))
@@ -60,8 +60,7 @@ print("RESULT" + json.dumps(out))
 
 
 def run(full: bool = False, quick: bool = False):
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = cpu_child_env()
     r = subprocess.run(
         [sys.executable, "-c", SCRIPT], env=env, capture_output=True, text=True,
         timeout=600,
@@ -73,7 +72,7 @@ def run(full: bool = False, quick: bool = False):
             for mode, d in data.items():
                 rows.append(emit(
                     "moe_dispatch", mode, 0.0,
-                    op="moe_dispatch", substrate=mode,
+                    op="moe_dispatch", substrate=mode, platform="cpu",
                     strategy_comm=d["strategy_comm"],
                     collective_bytes=d["collective_wire_bytes"],
                     collective_wire_mb=round(d["collective_wire_bytes"] / 1e6, 3),

@@ -26,7 +26,6 @@ miscounted payload dimension blows straight through it.
 from __future__ import annotations
 
 import json
-import os
 import subprocess
 import sys
 from pathlib import Path
@@ -34,7 +33,7 @@ from pathlib import Path
 import jax.numpy as jnp
 import numpy as np
 
-from .util import emit, emit_report
+from .util import cpu_child_env, emit, emit_report
 
 XCHECK_BAND = 8.0
 
@@ -88,9 +87,7 @@ def _run_xcheck_phase(scenarios) -> list:
     cases = [s for s in scenarios if s[3] % s[4] == 0]  # ep needs E % P == 0
     if not cases:
         return []
-    env = dict(os.environ)
-    src = str(Path(__file__).resolve().parents[1] / "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    env = cpu_child_env()
     proc = subprocess.run(
         [sys.executable, "-c", XCHECK_SCRIPT, str(XCHECK_BAND),
          json.dumps(cases)],
@@ -196,7 +193,7 @@ def run(full: bool = False, quick: bool = False):
     for rec in _run_xcheck_phase(scenarios):
         rows.append(emit(
             "moe", f"xcheck_{rec['scenario']}_{rec['mode']}", 0.0,
-            op="moe_dispatch", substrate="mesh",
+            op="moe_dispatch", substrate="mesh", platform="cpu",
             scenario=rec["scenario"], dispatch_mode=rec["mode"],
             modeled_bytes=rec["modeled_bytes"],
             lowered_wire_bytes=rec["lowered_wire_bytes"],

@@ -166,6 +166,9 @@ def main(argv=None) -> None:
     if args.require_model_band > 0 and not (args.calibrate or args.machine_file):
         ap.error("--require-model-band needs --calibrate or --machine-file "
                  "to have a model to gate")
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.machine_file:
         os.environ["REPRO_MACHINE_PATH"] = str(Path(args.machine_file).resolve())
     if args.calibrate:

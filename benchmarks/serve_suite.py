@@ -38,13 +38,12 @@ fail-closed SLO gate.
 from __future__ import annotations
 
 import json
-import os
 import subprocess
 import sys
 import tempfile
 from pathlib import Path
 
-from .util import emit
+from .util import cpu_child_env, emit
 
 POOL_STATS_PATH = (
     Path(__file__).resolve().parents[1] / "experiments" / "pool_stats.json"
@@ -379,9 +378,7 @@ print("SERVE-DECODE-OK")
 def _run_decode_phase(
     n_seqs: int, max_new: int, workers: int, slo_ms: float, require_p99_ms: float,
 ) -> dict:
-    env = dict(os.environ)
-    src = str(Path(__file__).resolve().parents[1] / "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    env = cpu_child_env()
     DECODE_STATS_PATH.parent.mkdir(parents=True, exist_ok=True)
     proc = subprocess.run(
         [sys.executable, "-c", DECODE_SCRIPT, str(DECODE_STATS_PATH),
@@ -401,9 +398,7 @@ def _run_pool_phase(
     grid: int, scale: int, tokens: int, reps: int, workers: int,
     min_speedup: float,
 ) -> dict:
-    env = dict(os.environ)
-    src = str(Path(__file__).resolve().parents[1] / "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    env = cpu_child_env()
     POOL_STATS_PATH.parent.mkdir(parents=True, exist_ok=True)
     proc = subprocess.run(
         [sys.executable, "-c", POOL_SCRIPT, str(POOL_STATS_PATH),
@@ -420,9 +415,7 @@ def _run_pool_phase(
 
 
 def _run_phase(phase: str, grids, scale: int, per: int) -> dict:
-    env = dict(os.environ)
-    src = str(Path(__file__).resolve().parents[1] / "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    env = cpu_child_env()
     with tempfile.NamedTemporaryFile(suffix=".json", delete=False) as tmp:
         out_path = tmp.name
     try:
@@ -468,7 +461,7 @@ def run(
     )
     for mode, d in decode["modes"].items():
         rows.append(emit(
-            "serve", f"decode_{mode}", d["wall_seconds"],
+            "serve", f"decode_{mode}", d["wall_seconds"], platform="cpu",
             op="moe_decode", substrate="local",
             nodelets=d["nodelets"], steps=d["steps"], tokens=d["tokens"],
             tokens_per_second=round(d["tokens_per_second"], 1),
@@ -482,13 +475,13 @@ def run(
         pool = _run_pool_phase(*pool_sizes, workers, min_pool_speedup)
         pooled = pool["stats_workers_pooled"]
         rows.append(emit(
-            "serve", "pool_baseline", pool["burst_wall_1"],
+            "serve", "pool_baseline", pool["burst_wall_1"], platform="cpu",
             requests=pool["requests_per_burst"],
             req_per_s=round(pool["throughput_1"], 1),
             workers=1,
         ))
         rows.append(emit(
-            "serve", "pool_workers", pool["burst_wall_pooled"],
+            "serve", "pool_workers", pool["burst_wall_pooled"], platform="cpu",
             requests=pool["requests_per_burst"],
             req_per_s=round(pool["throughput_pooled"], 1),
             workers=pool["workers"],
@@ -497,7 +490,7 @@ def run(
             worker_occupancy=[round(o, 3) for o in pooled["worker_occupancy"]],
         ))
         rows.append(emit(
-            "serve", "pool_speedup", pool["burst_wall_pooled"],
+            "serve", "pool_speedup", pool["burst_wall_pooled"], platform="cpu",
             pool_speedup=round(pool["pool_speedup"], 3),
             plan_keys=pool["plan_keys"],
             dedup_hits=pool["dedup_hits"],
@@ -506,7 +499,7 @@ def run(
         ))
     sync = _run_phase("sync", grids, scale, per)
     rows.append(emit(
-        "serve", "sync_drain", sync["wall_seconds"],
+        "serve", "sync_drain", sync["wall_seconds"], platform="cpu",
         requests=sync["requests"],
         req_per_s=round(sync["requests_per_second"], 1),
         compiles=sync["compiles"],
@@ -516,7 +509,7 @@ def run(
     ))
     a = _run_phase("async", grids, scale, per)
     rows.append(emit(
-        "serve", "async_worker", a["wall_seconds"],
+        "serve", "async_worker", a["wall_seconds"], platform="cpu",
         requests=a["requests"],
         req_per_s=round(a["requests_per_second"], 1),
         compiles=a["compiles"],
@@ -538,7 +531,7 @@ def run(
         sync["wall_seconds"] / a["wall_seconds"] if a["wall_seconds"] > 0 else 0.0
     )
     rows.append(emit(
-        "serve", "async_vs_sync", a["wall_seconds"],
+        "serve", "async_vs_sync", a["wall_seconds"], platform="cpu",
         sync_wall_seconds=round(sync["wall_seconds"], 4),
         speedup=round(speedup, 3),
         overlap_ratio=round(a["overlap_ratio"], 4),

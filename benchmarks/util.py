@@ -2,9 +2,25 @@
 unified RunReport row schema every suite emits."""
 from __future__ import annotations
 
+import os
 import time
+from pathlib import Path
 
 import jax
+
+
+def cpu_child_env() -> dict:
+    """Environment for a benchmark child process that forces host devices.
+
+    Such a child is a CPU rehearsal: ``JAX_PLATFORMS=cpu`` keeps it off the
+    chip, which the parent already holds once it has touched JAX (a chip
+    belongs to one process). Rows built from its results say
+    ``platform="cpu"``."""
+    env = dict(os.environ)
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"
+    return env
 
 
 def machine_header() -> dict:

@@ -209,6 +209,27 @@ class Cluster:
         self.shutdown()
 
 
+def _refuse_shared_chips(n_workers: int) -> None:
+    """A chip belongs to one process. Workers inherit this process's
+    environment and are given no device of their own, so on an accelerator
+    host every worker would claim the chips this process already holds.
+    Refuse that up front; CPU workers (``JAX_PLATFORMS=cpu``) own no chip."""
+    if os.environ.get("JAX_PLATFORMS") == "cpu":
+        return
+    import jax
+
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return
+    raise ClusterError(
+        f"refusing to start {n_workers} chip-owning worker(s): this process "
+        f"holds the host's {len(jax.devices())} {backend} device(s), and each "
+        "worker would contend for them (one process per chip). Set "
+        "JAX_PLATFORMS=cpu for CPU workers; giving each worker its own chip "
+        "is not implemented"
+    )
+
+
 def launch_cluster(
     n_workers: int = 2,
     *,
@@ -226,6 +247,9 @@ def launch_cluster(
 ) -> Cluster:
     """Stand up a localhost cluster and return its :class:`Cluster` handle.
 
+    On an accelerator host it refuses chip-owning workers
+    (:class:`ClusterError`) rather than let processes contend for a chip.
+
     ``activate=True`` (default) installs the coordinator as the process's
     active cluster so ``substrate="cluster"`` resolves everywhere.
     ``flush_window`` is the submit-coalescing window; ``blob_min_bytes``
@@ -235,6 +259,7 @@ def launch_cluster(
     """
     if n_workers < 1:
         raise ValueError(f"n_workers must be >= 1, got {n_workers}")
+    _refuse_shared_chips(n_workers)
     coordinator = Coordinator(
         heartbeat_interval=heartbeat_interval,
         heartbeat_timeout=heartbeat_timeout,

@@ -15,8 +15,9 @@ HOST:PORT --worker-id K``, it dials back to the coordinator, sends a
   Calls are wrapped in ``jax.jit`` with Python-scalar positional arguments
   pinned static — mirroring how the in-process plan cache closes over
   statics — and cached per value-independent signature, so repeated calls
-  hit a warm executable. Kernels the tracer rejects fall back to eager,
-  once, and stay pinned eager.
+  hit a warm executable. Kernels that need concrete values on the host
+  fall back to eager, once, and stay pinned eager; a compile error
+  propagates.
 - ``submit_many`` — a coordinator-coalesced frame: each item is a full
   submit (ticket + request) sharing the frame's segment table; they fan
   out to the pool exactly as if they had arrived one frame each.
@@ -78,8 +79,9 @@ class _KernelCache:
     Python-scalar positional args are made ``static_argnums`` — the same
     constant-folding the in-process executor gets by closing over them —
     so e.g. a BFS ``root`` or gsana ``k`` compiles exactly as it would
-    have locally. A kernel that refuses tracing runs eager and the key is
-    pinned eager from then on.
+    have locally. A kernel that needs concrete values on the host (the
+    tracer's concretization/conversion errors) runs eager and the key is
+    pinned eager from then on; every other error propagates.
     """
 
     def __init__(self):
@@ -112,8 +114,14 @@ class _KernelCache:
         try:
             result = jitted(*args)
             chosen = jitted
-        except Exception:
-            # host-side work the tracer cannot see: run (and stay) eager
+        except (
+            jax.errors.ConcretizationTypeError,
+            jax.errors.TracerArrayConversionError,
+            jax.errors.TracerIntegerConversionError,
+        ):
+            # host-side work the tracer cannot see: run (and stay) eager.
+            # Any other error (a compile refusal, a bad argument) propagates:
+            # rerunning it op by op would only hide it behind a slow path.
             def chosen(*xs):
                 return kern(*xs, **kwargs)
 
@@ -303,6 +311,9 @@ def main(argv: "list[str] | None" = None) -> None:
     parser.add_argument("--substrate", default="local")
     parser.add_argument("--service-workers", type=int, default=2)
     args = parser.parse_args(argv)
+    from ..runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     host, _, port = args.connect.rpartition(":")
     serve(
         (host, int(port)),

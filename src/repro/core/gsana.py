@@ -207,7 +207,6 @@ def compute_similarity_mesh(
     """
     from jax.sharding import PartitionSpec as P_
 
-    from ..compat import shard_map
     from .util import round_up
 
     nb = jnp.asarray(neighbor_buckets(b2.grid))
@@ -217,9 +216,9 @@ def compute_similarity_mesh(
         task = _all_task(vs1, vs2, b1, b2, nb, k)
         n_tasks = round_up(grid2, p)
         ids = jnp.minimum(jnp.arange(n_tasks, dtype=jnp.int32), grid2 - 1)
-        f = shard_map(
-            lambda s: jax.vmap(task)(s), mesh, in_specs=P_(axis_name),
-            out_specs=P_(axis_name),
+        f = jax.shard_map(
+            lambda s: jax.vmap(task)(s), mesh=mesh, in_specs=P_(axis_name),
+            out_specs=P_(axis_name), check_vma=False,
         )
         cand_b, score_b = f(ids)
         cand_b, score_b = cand_b[:grid2], score_b[:grid2]
@@ -230,9 +229,10 @@ def compute_similarity_mesh(
         pad = round_up(n_pairs, p) - n_pairs
         bids = jnp.pad(jnp.repeat(jnp.arange(grid2), 9), (0, pad))
         js = jnp.pad(jnp.tile(jnp.arange(9), grid2), (0, pad))
-        f = shard_map(
-            lambda b, j: jax.vmap(task)(b, j), mesh,
+        f = jax.shard_map(
+            lambda b, j: jax.vmap(task)(b, j), mesh=mesh,
             in_specs=(P_(axis_name), P_(axis_name)), out_specs=P_(axis_name),
+            check_vma=False,
         )
         cands, scores = f(bids, js)
         cand_b, score_b = _merge_pair_topk(cands[:n_pairs], scores[:n_pairs], grid2, k)

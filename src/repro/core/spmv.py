@@ -145,7 +145,6 @@ def spmv_mesh(
     """``mesh`` substrate: nodelet planes sharded over ``axis_name``. The
     non-replicated path pulls ``x`` with an ``all_gather`` (the migrate
     analogue). Same input/output conventions as :func:`spmv_local`."""
-    from ..compat import shard_map
     from jax.sharding import PartitionSpec as P_
 
     n = a.shape[1]
@@ -167,7 +166,10 @@ def spmv_mesh(
 
         in_specs = (P_(axis_name), P_(axis_name), P_(axis_name))
 
-    f = shard_map(body, mesh, in_specs=in_specs, out_specs=P_(axis_name))
+    f = jax.shard_map(
+        body, mesh=mesh, in_specs=in_specs, out_specs=P_(axis_name),
+        check_vma=False,
+    )
     return f(a.cols, a.vals, x)
 
 

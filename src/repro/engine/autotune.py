@@ -39,7 +39,7 @@ from ..core.cost import CostEstimate, cost_model_for
 from ..core.strategies import MigratoryStrategy, strategy_grid
 from ..machine.machine import MachineProfile, default_machine
 from ..machine.perfmodel import PerformanceModel
-from .api import ExecutionPlan, RunReport, strategy_dict
+from .api import ExecutionPlan, OpNotSupportedError, RunReport, strategy_dict
 from .cache import PlanCache
 from .ops import GRAIN_CANDIDATES  # noqa: F401  (legacy re-export; lives with the OpSpecs)
 from .probes import ProbeStore
@@ -58,7 +58,9 @@ def candidate_grid(
     ``substrate`` targets the grid at a backend: grid callables that accept
     an argument receive the substrate *kind* and may widen a kernel-tuning
     axis for it (SpMV/BFS enumerate Pallas ``block_rows``); zero-arg grids
-    are called as before, so the substrate-blind contract is unchanged."""
+    are called as before, so the substrate-blind contract is unchanged. A
+    backend that refuses the op here (:meth:`Substrate.refusal`) has no
+    grid: :class:`OpNotSupportedError`."""
     spec = default_registry().op_spec(op_name)
     if spec.grid is None:
         return strategy_grid()
@@ -66,7 +68,11 @@ def candidate_grid(
     if substrate is not None:
         from .substrate import get_substrate
 
-        kind = get_substrate(substrate).substrate_kind
+        sub = get_substrate(substrate)
+        reason = sub.refusal(op_name)
+        if reason is not None:
+            raise OpNotSupportedError(reason)
+        kind = sub.substrate_kind
     if inspect.signature(spec.grid).parameters:
         return spec.grid(kind)
     return spec.grid()

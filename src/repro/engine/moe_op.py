@@ -310,8 +310,6 @@ def _dispatch_mesh(
 ):
     from jax.sharding import PartitionSpec as P_
 
-    from ..compat import shard_map
-
     P, k = nodelets, experts_per_token
     T, D = x.shape
     E = router.shape[-1]
@@ -370,8 +368,8 @@ def _dispatch_mesh(
     else:
         raise ValueError(f"unknown dispatch mode {mode!r}")
 
-    f = shard_map(
-        body, mesh,
+    f = jax.shard_map(
+        body, mesh=mesh, check_vma=False,
         in_specs=(P_(axis_name), P_()) + (w_spec,) * len(ffn_args),
         out_specs=P_(axis_name),
     )

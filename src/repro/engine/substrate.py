@@ -102,20 +102,43 @@ class Substrate:
                 return own_name
         return self.name
 
+    def refusal(self, op_name: str) -> "str | None":
+        """Why this backend cannot run ``op_name`` here although a kernel
+        is registered (e.g. the chip's compiler refuses it), else None."""
+        del op_name
+        return None
+
     def kernel(self, op_name: str) -> Callable:
         """Resolve this backend's kernel for ``op_name`` (bound to self).
         Raises :class:`OpNotSupportedError` when no kernel is registered —
-        capability *is* registry presence."""
+        capability *is* registry presence — or when :meth:`refusal` names
+        a reason the registered kernel cannot run on this backend."""
+        reason = self.refusal(op_name)
+        if reason is not None:
+            raise OpNotSupportedError(reason)
         fn = default_registry().resolve_kernel(op_name, self.substrate_kind)
         return functools.partial(fn, self)
 
     def supports(self, op_name: str) -> bool:
-        return default_registry().has_kernel(op_name, self.substrate_kind)
+        return (
+            default_registry().has_kernel(op_name, self.substrate_kind)
+            and self.refusal(op_name) is None
+        )
 
     def cache_fingerprint(self) -> tuple:
         """Hashable identity for the compiled-plan cache: two substrate
         instances with equal fingerprints are interchangeable executors."""
         return (self.name,)
+
+
+def _one_device_slots() -> int:
+    """Executor slots for a substrate that runs on one device. On the CPU
+    backend, executions from different workers overlap in XLA's intra-op
+    pool, so size to the host's cores; an accelerator is one device, so
+    one slot."""
+    if jax.default_backend() == "cpu":
+        return max(1, os.cpu_count() or 1)
+    return 1
 
 
 class LocalSubstrate(Substrate):
@@ -124,9 +147,7 @@ class LocalSubstrate(Substrate):
     name = "local"
 
     def placement_slots(self) -> int:
-        # one device, many host cores: executions from different workers
-        # overlap in XLA's intra-op pool, so size to the core count
-        return max(1, os.cpu_count() or 1)
+        return _one_device_slots()
 
 
 class MeshSubstrate(Substrate):
@@ -198,9 +219,11 @@ class MeshSubstrate(Substrate):
             return self.mesh
         if self.device_window is not None:
             if p <= len(self.device_window):
-                from ..compat import make_mesh_over
-
-                return make_mesh_over(self.device_window[:p], (self.axis_name,))
+                return jax.make_mesh(
+                    (p,), (self.axis_name,),
+                    axis_types=(jax.sharding.AxisType.Auto,),
+                    devices=self.device_window[:p],
+                )
             # the plan spans more nodelets than this slot's window: fall
             # back to the global device mesh, audibly — such plans share
             # devices across slots (no disjoint-channel parallelism) and,
@@ -247,8 +270,27 @@ class PallasSubstrate(Substrate):
     def cache_fingerprint(self) -> tuple:
         return (self.name, self.interpret)
 
+    def refusal(self, op_name: str) -> "str | None":
+        """Kernels the TPU compiler refuses as written refuse here at plan
+        time, on a TPU, instead of falling back to interpret mode."""
+        why = TPU_LOWERING_REFUSALS.get(op_name)
+        if why is None or self.interpret or jax.default_backend() != "tpu":
+            return None
+        return f"pallas {op_name!r} kernel has no TPU lowering: {why}"
+
     def placement_slots(self) -> int:
-        return max(1, os.cpu_count() or 1)
+        return _one_device_slots()
+
+
+#: Pallas kernels that Mosaic refuses to lower for TPU as written, with the
+#: compiler's message (tests/test_tpu_compile.py pins both the refusals and
+#: the kernels that do compile). Rewriting them is kernel work of its own.
+TPU_LOWERING_REFUSALS = {
+    "spmv": "kernels/spmv gathers x[cols] with a 1-D jnp.take "
+    "('Only 2D gather is supported')",
+    "bfs": "kernels/bfs scatter-mins proposals with .at[].min "
+    "('Unimplemented primitive in Pallas TPU lowering: scatter-min')",
+}
 
 
 # -- built-in kernels ----------------------------------------------------------

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..runtime import resolve_interpret
+
 NEG_INF = float("-inf")
 
 
@@ -104,7 +106,7 @@ def flash_attention_folded(
     scale: float | None = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     bhq, sq, d = q.shape
     bhkv, skv, _ = k.shape
@@ -136,5 +138,5 @@ def flash_attention_folded(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

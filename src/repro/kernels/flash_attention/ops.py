@@ -19,9 +19,10 @@ def flash_attention(
     block_q: int = 128,
     block_k: int = 128,
     use_kernel: bool = True,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
-    """Tiled attention; pads sequence dims to block multiples internally."""
+    """Tiled attention; pads sequence dims to block multiples internally.
+    ``interpret=None`` resolves from the backend (:mod:`repro.kernels.runtime`)."""
     if not use_kernel:
         return attention_reference(q, k, v, causal=causal, window=window, scale=scale)
     b, hq, sq, d = q.shape

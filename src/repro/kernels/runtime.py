@@ -6,20 +6,19 @@ on accelerators that can lower Mosaic/Triton (TPU, GPU), interpret on
 everything else (CPU CI, the common case for this repo's tests). An
 explicit bool always wins — tests pin ``interpret=True`` for determinism,
 TPU runs may force ``interpret=False`` to fail loudly if lowering breaks.
+No wrapper defaults to ``True``: on a TPU a kernel either lowers or its
+caller refuses it (``PallasSubstrate.refusal``), it never interprets.
 
 The resolved value is part of the engine's compiled-plan cache key
-(``PallasSubstrate.cache_fingerprint``), so resolution must be stable for
-the life of the process — ``default_interpret`` caches the backend probe.
+(``PallasSubstrate.cache_fingerprint``); ``jax.default_backend()`` is fixed
+for the life of a process, so the resolution is too.
 """
 from __future__ import annotations
-
-import functools
 
 # backends whose Pallas lowering is native; everything else interprets
 _COMPILED_BACKENDS = ("tpu", "gpu", "cuda", "rocm")
 
 
-@functools.lru_cache(maxsize=None)
 def default_interpret(backend: "str | None" = None) -> bool:
     """True when Pallas kernels should run in interpret mode here."""
     if backend is None:

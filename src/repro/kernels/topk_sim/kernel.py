@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..runtime import resolve_interpret
+
 NEG = float("-inf")
 
 
@@ -52,10 +54,10 @@ def _topk_sim_kernel(
 ):
     fv = fv_ref[0]  # (A, F)
     fu = fu_ref[0]  # (B, F)
-    mv = mv_ref[0]  # (A,) validity
-    mu = mu_ref[0]  # (B,)
+    mv = mv_ref[0]  # (A, 1) validity
+    mu = mu_ref[0]  # (1, B)
     s = _sim_from_feats(fv, fu, t1, t2, t3)
-    valid = (mv > 0)[:, None] & (mu > 0)[None, :]
+    valid = (mv > 0) & (mu > 0)
     s = jnp.where(valid, s, NEG)
     a, b = s.shape
     cols = jax.lax.broadcasted_iota(jnp.int32, (a, b), 1)
@@ -79,7 +81,7 @@ def topk_sim_pallas(
     t2: int,
     t3: int,
     k: int = 4,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     p, a, f = feat_v.shape
     _, b, _ = feat_u.shape
@@ -90,8 +92,10 @@ def topk_sim_pallas(
         in_specs=[
             pl.BlockSpec((1, a, f), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, b, f), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, a), lambda i: (i, 0)),
-            pl.BlockSpec((1, b), lambda i: (i, 0)),
+            # masks as (P, A, 1) / (P, 1, B): each block's last two dims
+            # are the array's own, which the TPU's (8, 128) tiling accepts
+            pl.BlockSpec((1, a, 1), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 1, b), lambda i: (i, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, a, k), lambda i: (i, 0, 0)),
@@ -101,5 +105,5 @@ def topk_sim_pallas(
             jax.ShapeDtypeStruct((p, a, k), jnp.float32),
             jax.ShapeDtypeStruct((p, a, k), jnp.int32),
         ],
-        interpret=interpret,
-    )(feat_v, feat_u, mask_v, mask_u)
+        interpret=resolve_interpret(interpret),
+    )(feat_v, feat_u, mask_v[:, :, None], mask_u[:, None, :])

@@ -46,7 +46,7 @@ def topk_sim_pairs(
     vocab: tuple[int, int, int] = (16, 16, 64),
     k: int = 4,
     use_kernel: bool = True,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Run all PAIR tasks. Returns (scores (P, cap2, k), u_ids (P, cap2, k))."""
     t1, t2, t3 = vocab

@@ -12,10 +12,11 @@ DESIGN.md §8), and derives the three per-chip roofline terms:
 The peaks come from the machine file (DESIGN.md §1f): ``analyze`` divides
 by the :class:`~repro.machine.machine.Peaks` of the process-wide
 :func:`~repro.machine.machine.default_machine` (or an explicit ``machine=``
-profile). The bundled default carries the former hardcoded TPU-v5e-like
-constants (197 TFLOP/s bf16, 819 GB/s HBM, 50 GB/s/link), so uncalibrated
-output is unchanged; after ``python -m repro.machine.microbench`` the
-roofline speaks this host's sustained rates.
+profile). On an accelerator those are the published peaks of the device
+kind (``CHIP_PEAKS``; an unknown kind raises). On the CPU the bundled
+default carries the former hardcoded constants, marked as not a chip's;
+after ``python -m repro.machine.microbench`` the roofline speaks this
+host's sustained rates.
 """
 from __future__ import annotations
 

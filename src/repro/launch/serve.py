@@ -320,7 +320,9 @@ def main(argv=None) -> None:
                     help="with --cluster: SIGKILL one worker mid-stream to "
                          "demonstrate failover")
     args = ap.parse_args(argv)
+    from ..runtime.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     if args.cluster:
         cluster_demo(args.cluster, n_requests=args.ops_requests,
                      kill_one=args.cluster_kill_one)

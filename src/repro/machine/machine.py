@@ -94,24 +94,51 @@ class AlphaBeta:
         return cls(alpha=float(d["alpha"]), beta=float(d["beta"]))
 
 
+NOT_A_CHIP = "bundled default, not a chip"
+
+
 @dataclasses.dataclass(frozen=True)
 class Peaks:
     """Roofline peaks (launch/roofline.py reads these instead of its old
-    module constants)."""
+    module constants). ``source`` says where the numbers come from."""
 
     flops: float  # FLOP/s per chip
     hbm_bw: float  # bytes/s per chip
     ici_bw: float  # bytes/s per link
+    source: str = NOT_A_CHIP
 
-    def to_dict(self) -> dict[str, float]:
+    def to_dict(self) -> dict[str, Any]:
         return dataclasses.asdict(self)
 
     @classmethod
     def from_dict(cls, d: dict[str, Any]) -> "Peaks":
         return cls(
             flops=float(d["flops"]), hbm_bw=float(d["hbm_bw"]),
-            ici_bw=float(d["ici_bw"]),
+            ici_bw=float(d["ici_bw"]), source=str(d.get("source", NOT_A_CHIP)),
         )
+
+
+#: Published per-chip peaks keyed by ``jax.Device.device_kind``. On an
+#: accelerator backend these replace whatever peaks a profile carries; a
+#: device kind missing here is an error, never a default.
+CHIP_PEAKS = {
+    "TPU v5 lite": Peaks(
+        flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+        "819 GB/s HBM, 1,600 Gbit/s chip-to-chip over 4 links",
+    ),
+}
+
+
+def chip_peaks(device_kind: str) -> Peaks:
+    """The :data:`CHIP_PEAKS` row for ``device_kind``; an unknown kind raises."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add a row "
+            f"with its source to CHIP_PEAKS (known: {sorted(CHIP_PEAKS)})"
+        ) from None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -269,8 +296,8 @@ class MachineProfile:
 
 def _default_profile() -> MachineProfile:
     """The bundled conservative default: the roofline's former hardcoded
-    TPU-v5e peaks (197 TFLOP/s bf16, 819 GB/s HBM, 50 GB/s/link) plus
-    deliberately pessimistic CPU-host substrate terms. Everything works
+    peaks (197 TFLOP/s, 819 GB/s, 50 GB/s/link), marked as not a chip's,
+    plus deliberately pessimistic CPU-host substrate terms. Everything works
     against it; nothing *ranks* by it."""
     local = SubstrateProfile(
         stream_bw=8e9,  # ~1 DDR channel — conservative for any host
@@ -361,8 +388,12 @@ _cached: "tuple[str, float | None, MachineProfile] | None" = None
 def default_machine() -> MachineProfile:
     """The process-wide machine profile: the file at
     :func:`default_machine_path` when present and fresh, else
-    :data:`DEFAULT_PROFILE` (``calibrated=False``). Reloads automatically
-    when the file's mtime changes (``--calibrate`` mid-process works)."""
+    :data:`DEFAULT_PROFILE` (``calibrated=False``). On an accelerator the
+    peaks are the device kind's :data:`CHIP_PEAKS` row (an unknown kind
+    raises). Reloads automatically when the file's mtime changes
+    (``--calibrate`` mid-process works)."""
+    import jax
+
     global _cached
     path = default_machine_path()
     try:
@@ -374,6 +405,9 @@ def default_machine() -> MachineProfile:
         if _cached is not None and _cached[0] == key and _cached[1] == mtime:
             return _cached[2]
     profile = (load_machine(path) if mtime is not None else None) or DEFAULT_PROFILE
+    if jax.default_backend() != "cpu":
+        kind = jax.devices()[0].device_kind
+        profile = dataclasses.replace(profile, peaks=chip_peaks(kind))
     with _cache_lock:
         _cached = (key, mtime, profile)
     return profile

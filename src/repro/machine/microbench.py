@@ -169,7 +169,6 @@ def measure_collectives(
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from ..compat import shard_map
     from ..launch.mesh import make_nodelet_mesh
 
     n_dev = len(jax.devices())
@@ -192,8 +191,8 @@ def measure_collectives(
     out: dict[str, AlphaBeta] = {}
     for kind in kinds:
         f = jax.jit(
-            shard_map(
-                body(kind), mesh, in_specs=P(axis_name), out_specs=(
+            jax.shard_map(
+                body(kind), mesh=mesh, check_vma=False, in_specs=P(axis_name), out_specs=(
                     P() if kind == "psum" else P(axis_name)
                 ),
             )

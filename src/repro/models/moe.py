@@ -33,7 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from ..core.strategies import Comm, MigratoryStrategy
 from ..core.util import round_up
 from .config import ModelConfig
@@ -215,9 +214,10 @@ def _moe_tp(ctx: Ctx, p: dict, x: jax.Array, batch_axes) -> jax.Array:
             out = chunk_fn(xt)
         return out.reshape(bl, sl, d)
 
-    return shard_map(
+    return jax.shard_map(
         body,
-        mesh,
+        mesh=mesh,
+        check_vma=False,
         in_specs=(
             P(batch_axes, None, None),
             P(),  # router replicated
@@ -326,9 +326,10 @@ def _moe_ep(ctx: Ctx, p: dict, x: jax.Array, batch_axes, *, push: bool) -> jax.A
             out = jax.lax.all_gather(out, "model", tiled=True)
         return out.reshape(bl, sl, d)
 
-    return shard_map(
+    return jax.shard_map(
         body,
-        mesh,
+        mesh=mesh,
+        check_vma=False,
         in_specs=(
             P(batch_axes, None, None),
             P(),

@@ -46,6 +46,7 @@ def plan_remesh(
 
 
 def make_elastic_mesh(plan: ElasticPlan) -> jax.sharding.Mesh:
-    from ..compat import make_mesh
-
-    return make_mesh((plan.data_axis, plan.model_axis), ("data", "model"))
+    return jax.make_mesh(
+        (plan.data_axis, plan.model_axis), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2,
+    )

@@ -286,3 +286,69 @@ def test_resize_signal_custom_thresholds_and_to_dict():
     assert row["resize_signal"] == "hold"
     assert row["occupancy_hwm"] == 0.6
     assert row["worker_occupancy"] == [0.6, 0.6]
+
+
+# -- chip ownership and compile errors -----------------------------------------
+
+
+def test_launch_refuses_chip_owning_workers_on_an_accelerator(monkeypatch):
+    """A chip belongs to one process: on a TPU host the launcher refuses
+    workers that would inherit this process's chips, before starting any."""
+    import jax
+
+    from repro.cluster import launch_cluster
+    from repro.cluster.coordinator import ClusterError
+
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    started = []
+    monkeypatch.setattr(
+        "repro.cluster.launch.LocalProcessBackend.start",
+        lambda self, spec: started.append(spec),
+    )
+    with pytest.raises(ClusterError, match="one process per chip"):
+        launch_cluster(n_workers=1)
+    assert started == []
+
+
+class _FakeSubstrate:
+    """Just enough substrate for the worker's forwarded-kernel cache."""
+
+    def __init__(self, kern):
+        self._kern = kern
+
+    def cache_fingerprint(self):
+        return ("fake", id(self))
+
+    def kernel(self, op):
+        return self._kern
+
+
+def test_worker_kernel_cache_propagates_compile_errors():
+    """Only the tracer's host-side errors fall back to eager; any other
+    error from compiling a forwarded kernel propagates, and the kernel is
+    not rerun op by op."""
+    from repro.cluster.worker import _KernelCache
+
+    calls = []
+
+    def refused(x):
+        calls.append(type(x).__name__)
+        raise NotImplementedError("Only 2D gather is supported")
+
+    with pytest.raises(NotImplementedError, match="2D gather"):
+        _KernelCache().call(_FakeSubstrate(refused), "spmv", (jnp.ones(4),), {})
+    assert len(calls) == 1  # traced once, never rerun eagerly
+
+
+def test_worker_kernel_cache_runs_host_side_kernels_eagerly():
+    from repro.cluster.worker import _KernelCache
+
+    def host_side(x):
+        return jnp.asarray(np.asarray(x) * 2)  # needs concrete values
+
+    cache = _KernelCache()
+    sub = _FakeSubstrate(host_side)
+    for _ in range(2):  # second call hits the pinned-eager entry
+        out = cache.call(sub, "host", (jnp.arange(3.0),), {})
+        np.testing.assert_array_equal(np.asarray(out), [0.0, 2.0, 4.0])

@@ -61,6 +61,34 @@ def test_bfs_parent_levels_are_minimal():
         assert lv == ref_level[v], f"vertex {v}: {lv} != {ref_level[v]}"
 
 
+@pytest.mark.parametrize("corrupt", ["root", "missing_edge", "cycle", "out_of_range"])
+def test_validate_parents_rejects_invalid_trees(corrupt):
+    """The checker behind the chip smoke's BFS phases must refuse a tree
+    that is not one: a wrong root, a parent edge absent from the graph, a
+    cycle cut off from the root, a parent id past the last vertex."""
+    n = 256
+    g = edges_to_csr(erdos_renyi_edges(8, 4, seed=5), n)
+    pg = partition_graph(g, 4)
+    parents = np.asarray(bfs(pg, 7)).copy()
+    assert validate_parents(pg, 7, parents)
+    indptr, indices = np.asarray(g.indptr), np.asarray(g.indices)
+    reached = [v for v in np.flatnonzero(parents >= 0) if v != 7]
+    if corrupt == "root":
+        parents[7] = reached[0]
+    elif corrupt == "missing_edge":
+        v = reached[0]
+        nbrs = set(indices[indptr[v] : indptr[v + 1]].tolist())
+        parents[v] = next(u for u in reached if u != v and u not in nbrs)
+    elif corrupt == "cycle":
+        # two adjacent non-root vertices point at each other
+        v = reached[0]
+        u = next(int(w) for w in indices[indptr[v] : indptr[v + 1]] if w != 7)
+        parents[v], parents[u] = u, v
+    else:
+        parents[reached[0]] = n
+    assert not validate_parents(pg, 7, parents)
+
+
 def test_remote_write_traffic_beats_migrate():
     """Paper Fig. 7: put packets are far cheaper than thread migrations."""
     g = edges_to_csr(erdos_renyi_edges(10, 16, seed=1), 1024)

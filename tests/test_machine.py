@@ -149,6 +149,37 @@ def test_default_profile_is_uncalibrated_with_roofline_peaks():
     assert profile.substrate("tpu-pod") == profile.substrate("local")
 
 
+def test_chip_peaks_come_from_the_device_kind_table(monkeypatch):
+    """On an accelerator the peaks are the device kind's published row; a
+    kind missing from the table is an error, never the CPU default."""
+    import jax
+
+    from repro.machine.machine import CHIP_PEAKS, NOT_A_CHIP, chip_peaks
+
+    v5e = chip_peaks("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9)
+    assert "TPU v5e" in v5e.source and v5e.source != NOT_A_CHIP
+    assert DEFAULT_PROFILE.peaks.source == NOT_A_CHIP
+    with pytest.raises(ValueError, match="no published peaks"):
+        chip_peaks("TPU v99")
+
+    class _Chip:
+        def __init__(self, kind):
+            self.device_kind = kind
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda: [_Chip("TPU v5 lite")])
+    reset_default_machine_cache()
+    assert default_machine().peaks == CHIP_PEAKS["TPU v5 lite"]
+    monkeypatch.setattr(jax, "devices", lambda: [_Chip("TPU v99")])
+    reset_default_machine_cache()
+    with pytest.raises(ValueError, match="TPU v99"):
+        default_machine()
+    monkeypatch.undo()
+    reset_default_machine_cache()
+    assert default_machine().peaks == DEFAULT_PROFILE.peaks
+
+
 def test_default_machine_cache_tracks_mtime(tmp_path, monkeypatch):
     path = tmp_path / "machine.json"
     monkeypatch.setenv("REPRO_MACHINE_PATH", str(path))

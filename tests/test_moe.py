@@ -89,13 +89,14 @@ from repro.models.config import ModelConfig
 from repro.models.layers import Ctx
 from repro.models.moe import moe_params, moe_sublayer
 from repro.models.sharding import make_rules
-from repro.compat import make_mesh
 
 cfg = ModelConfig(name="t", family="moe", num_layers=1, d_model=64, num_heads=2,
                   num_kv_heads=2, d_ff=128, vocab_size=64, num_experts=8,
                   experts_per_token=2, moe_d_ff=32, capacity_factor=8.0,
                   dtype="float32", remat=False)
-mesh = make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh(
+    (4, 2), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2
+)
 rules = make_rules(mesh, num_experts=8, num_heads=2, num_kv_heads=2)
 ctx = Ctx(cfg=cfg, mesh=mesh, rules=rules)
 ctx1 = Ctx(cfg=cfg)

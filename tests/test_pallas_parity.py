@@ -143,6 +143,29 @@ def test_default_interpret_is_backend_aware():
     assert PallasSubstrate(interpret=False).interpret is False
 
 
+def test_no_pallas_wrapper_defaults_to_interpret_mode():
+    """Every Pallas entry point defaults to ``interpret=None`` (resolved from
+    the backend), so a TPU never runs the interpreter unasked — the LM
+    layer's flash call included, which passes no ``interpret`` at all."""
+    import inspect
+
+    from repro.kernels.bfs.kernel import bfs_expand_pallas
+    from repro.kernels.bfs.ops import bfs_expand, bfs_pallas
+    from repro.kernels.flash_attention.kernel import flash_attention_folded
+    from repro.kernels.flash_attention.ops import flash_attention
+    from repro.kernels.spmv.kernel import spmv_ell_pallas
+    from repro.kernels.spmv.ops import spmv
+    from repro.kernels.topk_sim.kernel import topk_sim_pallas
+    from repro.kernels.topk_sim.ops import topk_sim_pairs
+
+    for fn in (
+        bfs_expand_pallas, bfs_expand, bfs_pallas, flash_attention_folded,
+        flash_attention, spmv_ell_pallas, spmv, spmv_ell_stripes,
+        topk_sim_pallas, topk_sim_pairs,
+    ):
+        assert inspect.signature(fn).parameters["interpret"].default is None, fn
+
+
 # -- calibrated predicted-seconds ranking over the grain axis ------------------
 
 

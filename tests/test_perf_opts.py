@@ -18,8 +18,9 @@ cfg = dataclasses.replace(
     reduced_config("llama3.2-3b"), num_heads=6, num_kv_heads=2, head_dim=16,
     d_model=96, d_ff=192,
 )
-from repro.compat import make_mesh
-mesh = make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh(
+    (2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2
+)
 rules = make_rules(mesh, num_heads=6, num_kv_heads=2, vocab_size=cfg.vocab_size)
 assert rules.heads4d is None  # 6 % 4 != 0 -> baseline replicates attention
 params = api.init_params(cfg, jax.random.PRNGKey(0))

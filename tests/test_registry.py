@@ -243,3 +243,16 @@ def test_renamed_subclass_inherits_parent_kernels():
     assert PinnedKind().substrate_kind == "pallas"
     assert not PinnedKind().supports("moe_dispatch")  # pallas has no moe kernel
     assert PinnedKind().supports("bfs")  # ("bfs", "pallas") registered
+
+
+def test_one_device_substrates_take_one_slot_on_an_accelerator(monkeypatch):
+    """``workers="auto"`` sizes the pool from placement slots: host cores on
+    the CPU backend, but one slot on a chip, not a thread per host core all
+    driving the same device."""
+    import jax
+
+    from repro.engine.substrate import LocalSubstrate, PallasSubstrate
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert LocalSubstrate().placement_slots() == 1
+    assert PallasSubstrate().placement_slots() == 1

@@ -1,0 +1,168 @@
+"""Main-path kernels compiled ahead of time for a described TPU v5e.
+
+The TPU's compiler is installed with JAX, so each program here is lowered
+and compiled for a ``v5e:2x2`` topology that is described, not attached: a
+compile that passes says the chip's compiler accepts the program and its
+memory fits; it runs nothing. Sizes follow the chip smoke (``chip_smoke.py``):
+``laplacian_2d(2048)`` SpMV over 8 nodelets, uniform-random BFS at scale 21
+(edge factor 16, max degree ~68; the smoke runs scale 22, whose compile
+takes ~20 s on a CPU host), GSANA n=8192 PAIR tasks, and a
+32-head x 2048 x 128 bf16 attention.
+
+The Pallas SpMV and BFS kernels do not lower for the TPU as written; the
+last tests pin that, and that the ``pallas`` substrate refuses them at plan
+time on a TPU instead of interpreting them.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library, and test workers import every file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import MigratoryStrategy, bfs_local, spmv_local
+from repro.core.spmv import PartitionedELL
+from repro.engine import BFSInputs, BFSOp, OpNotSupportedError, SpMVInputs, SpMVOp
+from repro.engine.autotune import candidate_grid
+from repro.engine.runner import build_plan
+from repro.engine.substrate import TPU_LOWERING_REFUSALS, PallasSubstrate
+from repro.kernels.bfs.kernel import bfs_expand_pallas
+from repro.kernels.flash_attention.ops import flash_attention
+from repro.kernels.spmv.kernel import spmv_ell_pallas
+from repro.kernels.topk_sim.kernel import topk_sim_pallas
+from repro.sparse.graph import PartitionedGraph
+
+P = 8  # nodelets
+LAP_ROWS = 2048 * 2048
+BFS_SCALE, BFS_K = 21, 68
+GSANA_TASKS, GSANA_CAP, GSANA_FEATURES = 2304, 49, 5 + 16 + 16 + 64  # n=8192
+V5E_HBM_BYTES = 16 * 2**30
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without one; keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def shapes(one_chip, no_persistent_cache):
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    return shape
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
+    assert used < V5E_HBM_BYTES, f"{used / 2**30:.1f} GiB does not fit one v5e"
+    return compiled
+
+
+@pytest.mark.parametrize("replicate_x", [True, False], ids=["replicated_x", "striped_x"])
+def test_local_spmv_compiles_for_v5e(shapes, replicate_x):
+    rp = LAP_ROWS // P
+    a = PartitionedELL(
+        cols=shapes((P, rp, 5), jnp.int32), vals=shapes((P, rp, 5)),
+        shape=(LAP_ROWS, LAP_ROWS),
+    )
+    x = shapes((LAP_ROWS,)) if replicate_x else shapes((P, rp))
+    st = MigratoryStrategy(replicate_x=replicate_x)
+    _compile(lambda a, x: spmv_local(a, x, st), a, x)
+
+
+def test_local_bfs_compiles_for_v5e(shapes):
+    n = 1 << BFS_SCALE
+    g = PartitionedGraph(
+        adj=shapes((P, n // P, BFS_K), jnp.int32),
+        deg=shapes((P, n // P), jnp.int32),
+        n_vertices=n,
+    )
+    _compile(lambda g: bfs_local(g, 0), g)
+
+
+def test_flash_attention_compiles_for_v5e(shapes):
+    q = shapes((1, 32, 2048, 128), jnp.bfloat16)
+    compiled = _compile(lambda q, k, v: flash_attention(q, k, v, interpret=False), q, q, q)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_topk_similarity_compiles_for_v5e(shapes):
+    feats = shapes((GSANA_TASKS, GSANA_CAP, GSANA_FEATURES))
+    mask = shapes((GSANA_TASKS, GSANA_CAP))
+    compiled = _compile(
+        lambda fv, fu, mv, mu: topk_sim_pallas(
+            fv, fu, mv, mu, t1=16, t2=16, t3=64, k=4, interpret=False
+        ),
+        feats, feats, mask, mask,
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("op", sorted(TPU_LOWERING_REFUSALS))
+def test_refused_kernels_still_do_not_lower(shapes, op):
+    """The refusal table is what the compiler says today. When a kernel
+    starts to lower, this fails: drop its row so the engine serves it."""
+    rows = 4096
+    if op == "spmv":
+        fn = lambda c, v, x: spmv_ell_pallas(c, v, x, block_rows=256, interpret=False)  # noqa: E731
+        args = (shapes((rows, 8), jnp.int32), shapes((rows, 8)), shapes((rows,)))
+    else:
+        fn = lambda a, f: bfs_expand_pallas(a, f, block_rows=256, interpret=False)  # noqa: E731
+        args = (shapes((rows, 8), jnp.int32), shapes((rows,), jnp.int32))
+    says = {"spmv": "Only 2D gather is supported", "bfs": "scatter-min"}[op]
+    assert says in TPU_LOWERING_REFUSALS[op]
+    with pytest.raises(NotImplementedError, match=says):
+        jax.jit(fn).lower(*args).compile()
+
+
+@pytest.mark.parametrize("op", sorted(TPU_LOWERING_REFUSALS))
+def test_refused_kernels_refuse_at_plan_time_on_tpu(monkeypatch, op):
+    """With the backend reading ``tpu``, the pallas substrate resolves to
+    compiled kernels and refuses the ones that do not lower — at plan time
+    and in the autotune grid — instead of interpreting them."""
+    from repro.core import partition_ell
+    from repro.sparse import edges_to_csr, erdos_renyi_edges, laplacian_2d, partition_graph
+
+    if op == "spmv":
+        inputs = SpMVInputs(partition_ell(laplacian_2d(8), 4), jnp.ones(64, jnp.float32))
+        spec = SpMVOp()
+    else:
+        inputs = BFSInputs(partition_graph(edges_to_csr(erdos_renyi_edges(6, 4), 64), 4), 0)
+        spec = BFSOp()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sub = PallasSubstrate()
+    assert sub.interpret is False
+    assert not sub.supports(op)
+    with pytest.raises(OpNotSupportedError, match="no TPU lowering"):
+        build_plan(spec, inputs, MigratoryStrategy(), "pallas")
+    with pytest.raises(OpNotSupportedError, match="no TPU lowering"):
+        candidate_grid(op, "pallas")
+    # gsana's top-k kernel lowers: it stays servable on the chip
+    assert sub.supports("gsana")
+    monkeypatch.undo()
+    assert PallasSubstrate().supports(op)  # off the chip it interprets
+    build_plan(spec, inputs, MigratoryStrategy(), "pallas")
