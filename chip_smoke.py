@@ -31,10 +31,11 @@ runs, at sizes a graph or sparse user would call real:
 on the same inputs, and prints the devices each mesh spans.
 
 Each phase prints one ``phase {...}`` line: sizes, bytes on the device,
-compile seconds, requests, and whether the results matched. Its times are
-one cold run, not measurements. The last line is the device as JAX reports
-it, printed only on a TPU after every phase matched. No TPU, a phase that
-raises, or a result that does not match exits non-zero.
+the served path's XLA compiles per pipeline stage, requests, and whether
+the results matched. Its times are one cold run, not measurements. The
+last line is the device as JAX reports it, printed only on a TPU after
+every phase matched. No TPU, a phase that raises, or a result that does
+not match exits non-zero.
 """
 from __future__ import annotations
 
@@ -43,7 +44,6 @@ import dataclasses
 import gc
 import json
 import sys
-import threading
 import time
 from pathlib import Path
 
@@ -167,54 +167,33 @@ def serve(service: EngineService, requests: list) -> list:
 
 
 def first_call_seconds(responses: list) -> float:
-    """The engine's cold-call time of each new plan: compile plus its first
-    execution (``RunReport.compile_seconds``), summed."""
+    """The time of each new plan's cold call (``RunReport.compile_seconds``,
+    the ``engine.compile`` call: trace, compile and first execution),
+    summed."""
     return float(sum(r.report.compile_seconds for r in responses))
 
 
-_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
-
-
-class CompileClock:
-    """Seconds and count of XLA backend compiles (persistent-cache reads
-    included), from JAX's own monitoring events."""
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        self.seconds = 0.0
-        self.compiles = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event: str, duration: float, **_) -> None:
-        if event == _BACKEND_COMPILE:
-            with self._lock:
-                self.seconds += duration
-                self.compiles += 1
-
-    def read(self) -> tuple[float, float, int]:
-        with self._lock:
-            return time.perf_counter(), self.seconds, self.compiles
-
-
-CLOCK: "CompileClock | None" = None  # set by main()
-
-
-def begin() -> tuple[float, float, int]:
-    return CLOCK.read()
+def clock(service: EngineService) -> tuple[float, dict, dict]:
+    """Now, and the XLA compiles the service's served path has made so far
+    (count and seconds per pipeline stage, persistent-cache reads
+    included): the engine's compile counter."""
+    stats = service.stats()
+    return time.perf_counter(), stats.xla_compiles, stats.xla_compile_seconds
 
 
 def rel_err(y: np.ndarray, ref: np.ndarray) -> float:
     return float(np.max(np.abs(y.astype(np.float64) - ref)) / max(np.max(np.abs(ref)), 1e-30))
 
 
-def emit(phase: str, start: tuple, matched: bool, **fields) -> None:
-    now, compiled, compiles = CLOCK.read()
+def emit(service: EngineService, phase: str, start: tuple, matched: bool, **fields) -> None:
+    now, compiles, seconds = clock(service)
     row = {
         "phase": phase,
         **fields,
         **device_bytes(),
-        "xla_compiles": compiles - start[2],
-        "xla_compile_seconds": compiled - start[1],
+        "xla_compiles": {stage: n - start[1].get(stage, 0) for stage, n in compiles.items()
+                         if n > start[1].get(stage, 0)},
+        "xla_compile_seconds": sum(seconds.values()) - sum(start[2].values()),
         "cold_wall_seconds_one_run": now - start[0],
         "matched": matched,
     }
@@ -239,7 +218,7 @@ def pick_roots(csr: sp.csr_matrix, count: int, rng: np.random.Generator) -> list
 
 
 def phase_spmv(service, sizes, rng, ctx, p: int = 8) -> None:
-    start = begin()
+    start = clock(service)
     a = laplacian_2d(sizes.lap_n)
     ref_matrix = host_csr(a)
     n = a.n_rows
@@ -253,7 +232,7 @@ def phase_spmv(service, sizes, rng, ctx, p: int = 8) -> None:
     errs = [rel_err(np.asarray(gather_result(r.result, n)), ref) for r in responses]
     ctx["spmv"] = (inputs, np.asarray(gather_result(responses[0].result, n)), ref)
     emit(
-        "spmv", start, max(errs) <= SPMV_TOL,
+        service, "spmv", start, max(errs) <= SPMV_TOL,
         rows=n, nnz=int(ref_matrix.nnz), nodelets=p,
         strategies=["replicate_x", "striped_x"], requests=len(requests),
         input_bytes=tree_bytes((inputs.a, inputs.x)), first_call_seconds=first_call_seconds(responses),
@@ -271,7 +250,7 @@ def _bfs_graph(edges: np.ndarray, scale: int, p: int):
 
 
 def phase_bfs(service, name, edges, scale, sizes, rng, ctx, p: int = 8) -> None:
-    start = begin()
+    start = clock(service)
     graph, host = _bfs_graph(edges, scale, p)
     del edges
     graph_host = jax.device_get(graph)
@@ -292,7 +271,7 @@ def phase_bfs(service, name, edges, scale, sizes, rng, ctx, p: int = 8) -> None:
         reached_match &= reached[-1] == ref_reached
     ctx[name] = (BFSInputs(graph, roots[0]), np.asarray(responses[0].result))
     emit(
-        name, start, bool(trees_valid and reached_match),
+        service, name, start, bool(trees_valid and reached_match),
         scale=scale, vertices=graph.n_vertices, directed_edges=int(host.nnz),
         nodelets=p, padded_k=graph.k, roots=roots,
         strategies=["migrate", "remote_write"], requests=len(requests),
@@ -313,7 +292,7 @@ def _gsana_inputs(n: int, seed: int) -> GSANAInputs:
 
 
 def phase_gsana(service, sizes, seed, ctx) -> None:
-    start = begin()
+    start = clock(service)
     inputs = _gsana_inputs(sizes.gsana_n, seed)
     st = MigratoryStrategy(scheme=Scheme.PAIR)
     requests = [Request(GSANAOp(), inputs, st) for _ in range(2)]
@@ -331,7 +310,7 @@ def phase_gsana(service, sizes, seed, ctx) -> None:
     recall = recall_at_k(responses[0].result[0], inputs.ground_truth)
     ctx["gsana"] = (inputs, cand_o, score_o)
     emit(
-        "gsana", start, bool(identical and recall > RECALL_FLOOR),
+        service, "gsana", start, bool(identical and recall > RECALL_FLOOR),
         n=sizes.gsana_n, scheme="pair", k=k, buckets=inputs.b2.grid ** 2,
         bucket_cap=inputs.b2.cap, requests=len(requests),
         input_bytes=tree_bytes((inputs.vs1, inputs.vs2, inputs.b1, inputs.b2)),
@@ -344,7 +323,7 @@ def phase_gsana(service, sizes, seed, ctx) -> None:
 def phase_pallas(service, ctx) -> None:
     """Each Pallas kernel on its engine op at the sizes above. A kernel the
     TPU compiler refuses must refuse at plan time, never interpret."""
-    start = begin()
+    start = clock(service)
     sub = PallasSubstrate()
     spmv_inputs, spmv_local, _ = ctx["spmv"]
     bfs_inputs, bfs_local = ctx["bfs_urand"]
@@ -386,7 +365,7 @@ def phase_pallas(service, ctx) -> None:
         outcome[op] = "compiled, matches local" if ok else "compiled, DOES NOT match local"
         matched &= ok
     emit(
-        "pallas", start, matched,
+        service, "pallas", start, matched,
         interpret=sub.interpret, requests=len(cases), outcome=outcome,
         first_call_seconds=first_call_seconds(responses),
         score_atol=PALLAS_SCORE_ATOL,
@@ -403,7 +382,7 @@ def _drive(server, prompts, schedule) -> dict:
 
 
 def phase_moe_decode(service, seed) -> None:
-    start = begin()
+    start = clock(service)
     cfg = get_config("serve-moe")
     params = moe_decode_params(cfg, jax.random.PRNGKey(seed))
     rng = np.random.default_rng(seed)
@@ -421,7 +400,7 @@ def phase_moe_decode(service, seed) -> None:
         matched[label] = _drive(server, prompts, schedule) == oracle
         steps += server.steps
     emit(
-        "moe_decode", start, all(matched.values()),
+        service, "moe_decode", start, all(matched.values()),
         config="serve-moe: 1 layer, d_model 32; shows that the path runs, "
         "not how big it can be",
         sequences=len(prompts), requests=steps,
@@ -441,7 +420,7 @@ def _spans(result) -> int:
 
 
 def phase_mesh_spmv(service, sizes, rng, p: int = 4) -> None:
-    start = begin()
+    start = clock(service)
     a = laplacian_2d(sizes.lap_n)
     n = a.n_rows
     inputs = SpMVInputs(partition_ell(a, p), jnp.asarray(rng.standard_normal(n).astype(np.float32)))
@@ -455,7 +434,7 @@ def phase_mesh_spmv(service, sizes, rng, p: int = 4) -> None:
         errs.append(rel_err(np.asarray(gather_result(mesh.result, n)), ref))
         spans.append(_spans(mesh.result))
     emit(
-        "mesh_spmv", start, max(errs) <= MESH_TOL and min(spans) == p,
+        service, "mesh_spmv", start, max(errs) <= MESH_TOL and min(spans) == p,
         rows=n, nodelets=p, mesh_devices=_mesh_devices(p), result_spans_devices=spans,
         requests=len(requests), input_bytes=tree_bytes((inputs.a, inputs.x)),
         first_call_seconds=first_call_seconds(responses), max_rel_err_vs_local=max(errs),
@@ -464,7 +443,7 @@ def phase_mesh_spmv(service, sizes, rng, p: int = 4) -> None:
 
 
 def phase_mesh_bfs(service, sizes, rng, seed, p: int = 4) -> None:
-    start = begin()
+    start = clock(service)
     scale = sizes.mesh_urand_scale
     graph, host = _bfs_graph(erdos_renyi_edges(scale, 16, seed=seed), scale, p)
     roots = pick_roots(host, 2, rng)
@@ -480,7 +459,7 @@ def phase_mesh_bfs(service, sizes, rng, seed, p: int = 4) -> None:
         for local, mesh in zip(responses[0::2], responses[1::2])
     )
     emit(
-        "mesh_bfs", start, bool(equal),
+        service, "mesh_bfs", start, bool(equal),
         scale=scale, vertices=graph.n_vertices, directed_edges=int(host.nnz),
         nodelets=p, roots=roots, strategies=["migrate", "remote_write"],
         mesh_devices=_mesh_devices(p), requests=len(requests),
@@ -490,7 +469,7 @@ def phase_mesh_bfs(service, sizes, rng, seed, p: int = 4) -> None:
 
 
 def phase_mesh_moe(service, sizes, seed, p: int = 4) -> None:
-    start = begin()
+    start = clock(service)
     cfg = ModelConfig(
         name="smoke-moe", family="moe", num_layers=1, d_model=sizes.moe_d_model,
         num_heads=1, num_kv_heads=1, d_ff=sizes.moe_d_ff, vocab_size=64,
@@ -514,7 +493,7 @@ def phase_mesh_moe(service, sizes, seed, p: int = 4) -> None:
         errs[label] = rel_err(np.asarray(mesh.result), np.asarray(local.result).astype(np.float64))
         spans.append(_spans(mesh.result))
     emit(
-        "mesh_moe_dispatch", start, max(errs.values()) <= MESH_TOL and min(spans) == p,
+        service, "mesh_moe_dispatch", start, max(errs.values()) <= MESH_TOL and min(spans) == p,
         tokens=sizes.moe_tokens, d_model=cfg.d_model, experts=8, moe_d_ff=sizes.moe_d_ff,
         nodelets=p, mesh_devices=_mesh_devices(p), result_spans_devices=spans,
         requests=len(requests), input_bytes=tree_bytes((x, weights)),
@@ -542,8 +521,6 @@ def main(argv=None) -> int:
         print(f"chip_smoke: --chips {args.chips} needs {args.chips} devices, "
               f"JAX found {len(devices)}", file=sys.stderr)
         return 2
-    global CLOCK
-    CLOCK = CompileClock()
     print(f"# cache: {enable_compile_cache()}", flush=True)
     print(f"# devices: {len(devices)} x {devices[0].device_kind} ({platform})", flush=True)
     sizes = TINY if args.tiny else FULL

@@ -134,6 +134,12 @@ class RunReport:
     (BFS rounds / GSANA plan model), effective bandwidth, and the plan
     cache's compile accounting (``cache_hit``, ``compile_seconds``).
 
+    ``compile_seconds`` is the time of the plan's cold call, the service's
+    ``engine.compile`` stage: trace, XLA compile (or persistent-cache read)
+    and the first execution, 0.0 on a cache hit. The XLA compile seconds
+    alone are the engine's compile counter
+    (``ServiceStats.xla_compile_seconds``, :mod:`~repro.engine.spans`).
+
     ``predicted_seconds``/``model_error`` are the calibration plane's
     honesty columns (DESIGN.md §1f): the performance model's wall-seconds
     prediction for this plan and its ratio to the measurement

@@ -4,7 +4,8 @@ later plan with the same shape/dtype/strategy/substrate signature
 
 The engine's plan -> compile -> execute pipeline looks executors up here.
 A *miss* wraps the plan's executor in ``jax.jit`` (unless the plan opted out
-with ``jit=False``) and marks the entry pending; the runner times the
+with ``jit=False``) under the name ``<op>_<substrate>``, so the device trace
+reads ``jit_spmv_local(...)`` for each program, and marks the entry pending; the runner times the
 executor's first call (trace + XLA compile + first run on this signature)
 and records it via :meth:`PlanCache.note_compiled`. A *hit* hands back the
 already-warm executable, so the call skips tracing entirely and the run's
@@ -39,12 +40,26 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import re
 import threading
 from typing import Any, Callable
 
 import jax
 
 from .api import ExecutionPlan
+
+
+def _named(plan: ExecutionPlan) -> Callable[..., Any]:
+    """The plan's executor under the name ``<op>_<substrate>``, which
+    ``jax.jit`` gives its program. It closes over the executor only: the
+    cache keeps it alive, and it must not pin the plan's input arrays."""
+    executor = plan.executor
+
+    def call(*args):
+        return executor(*args)
+
+    call.__name__ = call.__qualname__ = re.sub(r"\W", "_", f"{plan.op}_{plan.substrate}")
+    return call
 
 
 @dataclasses.dataclass
@@ -120,7 +135,7 @@ class PlanCache:
                 # entry exists but its first call never ran: still a cold path
                 self.misses += 1
                 return CompiledPlan(plan, entry.executor, cache_hit=False, entry=entry)
-            executor = jax.jit(plan.executor) if plan.jit else plan.executor
+            executor = jax.jit(_named(plan)) if plan.jit else plan.executor
             entry = CacheEntry(executor=executor, slot=slot)
             self._entries[plan.key] = entry
             while len(self._entries) > self.max_entries:

@@ -12,10 +12,13 @@ The stages are individually exposed (DESIGN.md §1):
   (``iters=3, warmup=1``) report *steady-state* medians with compile cost
   split into ``RunReport.compile_seconds``; pass ``iters=1, warmup=0`` to
   time a single cold call (compile included in ``seconds`` on a cache miss).
+
+Each call opens the spans ``engine.dispatch`` (until the executor returns
+unready arrays) and ``engine.device`` (``block_until_ready``), and the
+report's derived stats run under ``engine.derived`` (:mod:`.spans`).
 """
 from __future__ import annotations
 
-import time
 from typing import Any
 
 import jax
@@ -26,6 +29,7 @@ from .api import ExecutionPlan, MigratoryOp, RunReport
 from .cache import CompiledPlan, PlanCache, default_cache
 from .registry import default_registry
 from .request import Request, coerce_request
+from .spans import DERIVED, DEVICE, DISPATCH, span
 from .substrate import Substrate, get_substrate
 
 
@@ -94,11 +98,24 @@ def compile_plan(
     return (default_cache() if cache is None else cache).get(plan, slot=slot)
 
 
-def _timed_call(compiled: CompiledPlan, times: list[float]) -> Any:
-    t0 = time.perf_counter()
-    result = jax.block_until_ready(compiled())
-    times.append(time.perf_counter() - t0)
-    return result
+def _timed_call(
+    compiled: "CompiledPlan | ExecutionPlan",
+    times: list[float],
+    cache: PlanCache | None = None,
+    slot: "int | None" = None,
+) -> tuple[CompiledPlan, Any]:
+    """One call, its seconds appended to ``times``, under the spans
+    ``engine.dispatch`` (until the executor returns unready arrays) and
+    ``engine.device`` (``block_until_ready``). A bare plan is looked up in
+    the cache inside the dispatch span. Returns ``(compiled, result)``."""
+    with span(DISPATCH) as dispatch:
+        if isinstance(compiled, ExecutionPlan):
+            compiled = compile_plan(compiled, cache, slot=slot)
+        pending = compiled()
+    with span(DEVICE) as device:
+        result = jax.block_until_ready(pending)
+    times.append(device.t1 - dispatch.t0)
+    return compiled, result
 
 
 def execute(
@@ -115,29 +132,34 @@ def execute(
     unmeasured ones. On a cache miss the first call traces + compiles; it is
     recorded as ``compile_seconds`` and doubles as the first warmup call —
     or, with ``warmup=0``, lands inside the timed set so a single cold call
-    is timed compile-inclusive (the pre-cache engine's behavior).
+    is timed compile-inclusive (the pre-cache engine's behavior). A bare
+    plan is resolved through the cache inside the first call.
     """
-    if isinstance(compiled, ExecutionPlan):
-        compiled = compile_plan(compiled, cache, slot=slot)
-    timed: list[float] = []
+    return _execute(compiled, iters, warmup, cache, slot)[1:]
+
+
+def _execute(
+    compiled: "CompiledPlan | ExecutionPlan",
+    iters: int,
+    warmup: int,
+    cache: PlanCache | None,
+    slot: "int | None",
+) -> tuple[CompiledPlan, Any, float, float]:
+    """:func:`execute`, also returning the :class:`CompiledPlan` it ran."""
+    first: list[float] = []
+    compiled, result = _timed_call(compiled, first, cache, slot)
     compile_seconds = 0.0
-    result = None
-    n_warm = warmup
     if not compiled.cache_hit:
-        first: list[float] = []
-        result = _timed_call(compiled, first)
         compile_seconds = first[0]
         (default_cache() if cache is None else cache).note_compiled(compiled, compile_seconds)
-        if warmup > 0:
-            n_warm = warmup - 1  # the compiling call was the first warmup
-        else:
-            timed.append(compile_seconds)  # cold-timing mode
-    for _ in range(n_warm):
-        result = _timed_call(compiled, [])
+    # the first call is the first warmup, or with warmup=0 the first timed one
+    timed = [] if warmup > 0 else first
+    for _ in range(warmup - 1):
+        _, result = _timed_call(compiled, [])
     for _ in range(max(1, iters) - len(timed)):
-        result = _timed_call(compiled, timed)
+        _, result = _timed_call(compiled, timed)
     timed.sort()
-    return result, timed[len(timed) // 2], compile_seconds
+    return compiled, result, timed[len(timed) // 2], compile_seconds
 
 
 def single_call(
@@ -175,30 +197,29 @@ def run_plan(
     cache: PlanCache | None = None,
     slot: "int | None" = None,
 ) -> tuple[Any, RunReport]:
-    """Compile + execute an already-built plan and assemble its RunReport."""
-    compiled = compile_plan(plan, cache, slot=slot)
-    result, seconds, compile_seconds = execute(
-        compiled, iters=iters, warmup=warmup, cache=cache
-    )
+    """Compile + execute an already-built plan and assemble its RunReport;
+    the derived stats run under the span ``engine.derived``."""
+    compiled, result, seconds, compile_seconds = _execute(plan, iters, warmup, cache, slot)
     # model honesty columns (DESIGN.md §1f): only a *calibrated* machine
     # file produces predictions — without one the report is bit-identical
     # to the pre-calibration schema (the columns stay None and are omitted
     # from to_dict), and the lookup is one cached profile check
     from ..machine.perfmodel import maybe_predict_plan_seconds
 
-    predicted = maybe_predict_plan_seconds(op, plan)
-    report = RunReport.from_parts(
-        op=op.name,
-        strategy=plan.strategy,
-        substrate=plan.substrate,
-        seconds=seconds,
-        traffic=op.traffic(plan),
-        bytes_moved=op.bytes_moved(plan),
-        metrics=op.metrics(plan, result, seconds),
-        cache_hit=compiled.cache_hit,
-        compile_seconds=compile_seconds,
-        predicted_seconds=predicted,
-    )
+    with span(DERIVED):
+        predicted = maybe_predict_plan_seconds(op, plan)
+        report = RunReport.from_parts(
+            op=op.name,
+            strategy=plan.strategy,
+            substrate=plan.substrate,
+            seconds=seconds,
+            traffic=op.traffic(plan),
+            bytes_moved=op.bytes_moved(plan),
+            metrics=op.metrics(plan, result, seconds),
+            cache_hit=compiled.cache_hit,
+            compile_seconds=compile_seconds,
+            predicted_seconds=predicted,
+        )
     return result, report
 
 

@@ -64,6 +64,7 @@ from .api import RunReport
 from .cache import PlanCache
 from .request import Request, coerce_request
 from .runner import build_plan, resolve_op, single_call
+from .spans import COMPILE, RESOLVE, RUN, SCHEDULE, SUBMIT, SpanTotals, span
 from .substrate import Substrate, get_substrate
 
 # per-request latency samples kept for percentile estimation (newest wins;
@@ -311,6 +312,13 @@ class ServiceStats:
       executed requests / steals / occupancy (busy ÷ serving window). One
       ``to_dict()`` row carries the merged view so bench artifacts stay a
       single record per run.
+    - ``span_seconds``/``span_counts`` — per span name (:mod:`.spans`:
+      ``engine.submit`` ... ``engine.resolve``), total seconds and count;
+      ``xla_compiles``/``xla_compile_seconds`` — XLA compiles per pipeline
+      stage (``engine.compile``: cold plans; ``engine.run``: a recompile
+      on the warm path). The timings above take their clock readings from
+      these spans: queue wait is admission -> ``engine.run`` start, service
+      time ``engine.run`` start -> ``engine.resolve`` start.
     """
 
     requests: int = 0
@@ -360,6 +368,10 @@ class ServiceStats:
     wire_bytes_received: int = 0
     blob_hits: int = 0  # blobrefs resolved from a local blob store
     blob_misses: int = 0  # blobrefs that needed a need_blob re-fetch
+    span_seconds: "dict[str, float]" = dataclasses.field(default_factory=dict)
+    span_counts: "dict[str, int]" = dataclasses.field(default_factory=dict)
+    xla_compiles: "dict[str, int]" = dataclasses.field(default_factory=dict)
+    xla_compile_seconds: "dict[str, float]" = dataclasses.field(default_factory=dict)
 
     @property
     def requests_per_second(self) -> float:
@@ -448,6 +460,10 @@ class ServiceStats:
             "wire_bytes_received": self.wire_bytes_received,
             "blob_hits": self.blob_hits,
             "blob_misses": self.blob_misses,
+            "span_seconds": self.span_seconds,
+            "span_counts": self.span_counts,
+            "xla_compiles": self.xla_compiles,
+            "xla_compile_seconds": self.xla_compile_seconds,
             "resize_signal": self.resize_signal(),
             "requests_per_second": self.requests_per_second,
             "amortization": self.amortization,
@@ -536,6 +552,7 @@ class EngineService:
         self._pending: list[ServiceRequest] = []
         self._next_ticket = 0
         self._stats = ServiceStats()
+        self._spans = SpanTotals()  # its own lock, not self._lock
         # worker-loop state: one lock, five conditions on it
         self._lock = threading.Lock()
         self._work = threading.Condition(self._lock)  # scheduler: items arrived
@@ -640,8 +657,16 @@ class EngineService:
         content hash matches an already-*served* response resolves
         immediately, and one matching a *pending* identical request
         coalesces onto its future — neither enters the queue (batch mode
-        dedups inside ``drain()``)."""
+        dedups inside ``drain()``). The call is the span ``engine.submit``,
+        under the request's ticket."""
         request = coerce_request(op, inputs, strategy, substrate, entry="submit")
+        with self._lock:
+            ticket = self._next_ticket
+            self._next_ticket += 1
+        with span(SUBMIT, ticket, self._spans):
+            return self._submit(request, ticket)
+
+    def _submit(self, request: Request, ticket: int) -> "int | ServiceFuture":
         op, inputs, strategy = request.op, request.inputs, request.strategy
         if strategy is None and self.autotune:
             strategy = "auto"
@@ -657,22 +682,20 @@ class EngineService:
             dkey = _content_hash(op, inputs, strategy, sub)  # outside the lock
         with self._lock:
             if dkey is not None and self._running and not self._stopping:
-                served = self._dedup_submit_locked(dkey)
+                served = self._dedup_submit_locked(dkey, ticket)
                 if served is not None:
                     return served
             self._admit_locked()
             if dkey is not None and self._running:
                 # _admit_locked may have blocked; the answer (or a pending
                 # primary) may have appeared while we waited
-                served = self._dedup_submit_locked(dkey)
+                served = self._dedup_submit_locked(dkey, ticket)
                 if served is not None:
                     return served
-            ticket = self._next_ticket
-            self._next_ticket += 1
             req = ServiceRequest(
                 ticket=ticket,
                 op=op,
-                inputs=request.inputs,
+                inputs=inputs,
                 strategy=strategy,
                 substrate=sub,
                 t_admit=time.perf_counter(),
@@ -700,14 +723,12 @@ class EngineService:
             )
             return ticket
 
-    def _dedup_submit_locked(self, dkey: str) -> "ServiceFuture | None":
+    def _dedup_submit_locked(self, dkey: str, ticket: int) -> "ServiceFuture | None":
         """Submit-time dedup: serve from the response store, or coalesce
         onto a pending identical request. None = no hit, enqueue normally."""
         hit = self._dedup_store.get(dkey)
         if hit is not None:
             self._dedup_store.move_to_end(dkey)
-            ticket = self._next_ticket
-            self._next_ticket += 1
             self._stats.requests += 1
             self._stats.dedup_hits += 1
             future = ServiceFuture(ticket)
@@ -721,15 +742,11 @@ class EngineService:
                 resp = prim.future._response
                 if resp is None:
                     return None  # primary failed: caller becomes a new primary
-                ticket = self._next_ticket
-                self._next_ticket += 1
                 self._stats.requests += 1
                 self._stats.dedup_hits += 1
                 future = ServiceFuture(ticket)
                 future._resolve(ServiceResponse(ticket, resp.result, resp.report))
                 return future
-            ticket = self._next_ticket
-            self._next_ticket += 1
             future = ServiceFuture(ticket)
             prim.waiters.append((ticket, future))
             self._live[ticket] = future
@@ -880,7 +897,9 @@ class EngineService:
                     self._space.notify_all()
                 try:
                     dispatched: set[int] = set()
-                    for items in self._plan_groups(snapshot):
+                    with span(SCHEDULE, snapshot[0].request.ticket, self._spans):
+                        planned = self._plan_groups(snapshot)
+                    for items in planned:
                         with self._lock:
                             # stop(drain=False) after the snapshot was taken:
                             # honor it — groups not yet compiled or handed to
@@ -892,7 +911,8 @@ class EngineService:
                                         dispatched.add(id(item))
                                 self._idle.notify_all()
                                 continue
-                        group = self._place_group(items)
+                        with span(SCHEDULE, items[0].request.ticket, self._spans):
+                            group = self._place_group(items)
                         if group is None:
                             continue
                         with self._lock:
@@ -1016,22 +1036,20 @@ class EngineService:
                     (w, group.first_ticket, group.qos, group.stolen)
                 )
                 self._pool_space.notify_all()
-            t0 = time.perf_counter()
-            served = 0
+            runs = []
             while True:
                 with self._lock:
                     if not group.items:
                         break
                     item = group.items.popleft()
-                self._run_item(item, slot=w)
-                served += 1
-            t1 = time.perf_counter()
+                runs.append(self._run_item(item, slot=w))
             with self._lock:
                 self._pool_current[w] = None
-                if served:
+                if runs:
+                    t0, t1 = runs[0].t0, runs[-1].t1
                     self._worker_spans[w].append((t0, t1))
                     self._worker_busy[w] += t1 - t0
-                    self._worker_reqs[w] += served
+                    self._worker_reqs[w] += len(runs)
                     self._note_span_end_locked(t1)
                     self._maybe_fold_spans_locked()
 
@@ -1154,12 +1172,11 @@ class EngineService:
         (possibly compiling) call on the scheduler thread — pinning the
         entry to ``slot`` — while the pool executes other groups; the
         group's later members are cache hits by construction."""
-        t0 = time.perf_counter()
-        self._run_item(item, slot=slot)
-        t1 = time.perf_counter()
+        with span(COMPILE, item.request.ticket, self._spans) as compiling:
+            self._run_item(item, slot=slot)
         with self._lock:
-            self._compile_spans.append((t0, t1))
-            self._note_span_end_locked(t1)
+            self._compile_spans.append((compiling.t0, compiling.t1))
+            self._note_span_end_locked(compiling.t1)
             self._maybe_fold_spans_locked()
 
     def _note_span_end_locked(self, t1: float) -> None:
@@ -1190,43 +1207,47 @@ class EngineService:
         for spans in self._worker_spans:
             spans.clear()
 
-    def _run_item(self, item: _WorkItem, slot: "int | None" = None) -> None:
-        t0 = time.perf_counter()
-        if item.dedup_key is not None and self._try_serve_dedup(item):
-            return
-        if self._shed_if_expired(item, t0):
-            return
-        try:
-            result, report = single_call(
-                item.plan, item.op, cache=self.cache, slot=slot
-            )
-        except Exception as exc:
-            self._finish_error(item, exc)
-            return
-        t1 = time.perf_counter()
-        response = ServiceResponse(item.request.ticket, result, report)
-        item.future._resolve(response)
-        with self._lock:
-            self._live.pop(item.request.ticket, None)
-            if item.dedup_key is not None:
-                self._dedup_store[item.dedup_key] = response
-                self._dedup_store.move_to_end(item.dedup_key)
-                while len(self._dedup_store) > self.dedup_max_entries:
-                    self._dedup_store.popitem(last=False)
-                if self._dedup_pending.get(item.dedup_key) is item:
-                    del self._dedup_pending[item.dedup_key]
-            self._resolve_waiters_locked(item, response)
-            if item.request.t_admit:
-                self._queue_waits.append(max(0.0, t0 - item.request.t_admit))
-                total = max(0.0, t1 - item.request.t_admit)
-                self._total_latencies.append(total)
-                if self.slo_target_seconds is not None:
-                    self._stats.slo_checked += 1
-                    if total > self.slo_target_seconds:
-                        self._stats.slo_violations += 1
-            self._service_times.append(t1 - t0)
-            self._account_locked(report)
-            self._finish_locked()
+    def _run_item(self, item: _WorkItem, slot: "int | None" = None) -> span:
+        """Serve one request under the span ``engine.run``; returns the span.
+        Queue wait runs to its start, service time from there to the start
+        of ``engine.resolve``."""
+        with span(RUN, item.request.ticket, self._spans) as run:
+            if item.dedup_key is not None and self._try_serve_dedup(item):
+                return run
+            if self._shed_if_expired(item, run.t0):
+                return run
+            try:
+                result, report = single_call(
+                    item.plan, item.op, cache=self.cache, slot=slot
+                )
+            except Exception as exc:
+                self._finish_error(item, exc)
+                return run
+            with span(RESOLVE) as resolve:
+                response = ServiceResponse(item.request.ticket, result, report)
+                item.future._resolve(response)
+                with self._lock:
+                    self._live.pop(item.request.ticket, None)
+                    if item.dedup_key is not None:
+                        self._dedup_store[item.dedup_key] = response
+                        self._dedup_store.move_to_end(item.dedup_key)
+                        while len(self._dedup_store) > self.dedup_max_entries:
+                            self._dedup_store.popitem(last=False)
+                        if self._dedup_pending.get(item.dedup_key) is item:
+                            del self._dedup_pending[item.dedup_key]
+                    self._resolve_waiters_locked(item, response)
+                    if item.request.t_admit:
+                        self._queue_waits.append(max(0.0, run.t0 - item.request.t_admit))
+                        total = max(0.0, resolve.t0 - item.request.t_admit)
+                        self._total_latencies.append(total)
+                        if self.slo_target_seconds is not None:
+                            self._stats.slo_checked += 1
+                            if total > self.slo_target_seconds:
+                                self._stats.slo_violations += 1
+                    self._service_times.append(resolve.t0 - run.t0)
+                    self._account_locked(report)
+                    self._finish_locked()
+        return run
 
     def _resolve_waiters_locked(
         self, item: _WorkItem, response: ServiceResponse
@@ -1380,7 +1401,8 @@ class EngineService:
         with self._lock:
             self._inflight += len(items)  # balanced by _finish_locked per item
         try:
-            groups = self._plan_groups(items)
+            with span(SCHEDULE, items[0].request.ticket, self._spans):
+                groups = self._plan_groups(items)
             # fail fast, like the pre-worker-loop drain: a plan that would
             # not bind raises before any group spends compile/execute time
             bad = next(
@@ -1393,7 +1415,11 @@ class EngineService:
                 with self._lock:
                     self._stats.batches += 1
                 for item in group:
-                    self._run_item(item)
+                    if self.cache.is_warm(item.plan.key):
+                        self._run_item(item)
+                    else:
+                        with span(COMPILE, item.request.ticket, self._spans):
+                            self._run_item(item)
                     if item.future._exception is not None:
                         raise item.future._exception
                     responses.append(item.future._response)
@@ -1417,6 +1443,7 @@ class EngineService:
         columns attached (see :class:`ServiceStats` for semantics). Each
         call returns a fresh object — safe to keep for before/after
         comparisons."""
+        spans = self._spans.snapshot()
         with self._lock:
             worker_wall = (
                 self._t_last - self._t_first
@@ -1462,6 +1489,7 @@ class EngineService:
                 worker_occupancy=occupancy,
                 occupancy_hwm=self._occ_hwm,
                 slo_target_seconds=self.slo_target_seconds,
+                **spans,
             )
         waits.sort()
         services.sort()

@@ -109,15 +109,24 @@ class Substrate:
         return None
 
     def kernel(self, op_name: str) -> Callable:
-        """Resolve this backend's kernel for ``op_name`` (bound to self).
-        Raises :class:`OpNotSupportedError` when no kernel is registered —
-        capability *is* registry presence — or when :meth:`refusal` names
-        a reason the registered kernel cannot run on this backend."""
+        """Resolve this backend's kernel for ``op_name`` (bound to self),
+        traced under ``jax.named_scope("<op>.<substrate>")`` so its XLA ops
+        carry that scope. Raises :class:`OpNotSupportedError` when no kernel
+        is registered — capability *is* registry presence — or when
+        :meth:`refusal` names a reason the registered kernel cannot run on
+        this backend."""
         reason = self.refusal(op_name)
         if reason is not None:
             raise OpNotSupportedError(reason)
         fn = default_registry().resolve_kernel(op_name, self.substrate_kind)
-        return functools.partial(fn, self)
+        scope = f"{op_name}.{self.name}"
+
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(scope):
+                return fn(self, *args, **kwargs)
+
+        return scoped
 
     def supports(self, op_name: str) -> bool:
         return (

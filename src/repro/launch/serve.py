@@ -72,6 +72,18 @@ def _ops_workload(shapes: tuple[int, ...], seed: int):
     return pick
 
 
+
+def _print_spans(stats) -> None:
+    """The engine's span totals (mean ms per span, count) and the XLA
+    compiles per pipeline stage: the operator's view of the served path."""
+    spans = ", ".join(
+        f"{name} {stats.span_seconds[name] / count * 1e3:.2f} ms x{count}"
+        for name, count in stats.span_counts.items()
+    )
+    print(f"spans: {spans}")
+    print(f"xla compiles by stage: {stats.xla_compiles} "
+          f"({sum(stats.xla_compile_seconds.values())*1e3:.0f} ms)")
+
 def ops_demo(n_requests: int, shapes: tuple[int, ...] = (16, 24), seed: int = 0) -> dict:
     """Serve a mixed irregular-op workload through the batched EngineService.
 
@@ -93,6 +105,7 @@ def ops_demo(n_requests: int, shapes: tuple[int, ...] = (16, 24), seed: int = 0)
     print(f"compiles: {stats.compiles} ({stats.compile_seconds*1e3:.0f} ms), "
           f"cache hits: {stats.cache_hits}, "
           f"amortization: {stats.amortization:.1f} req/compile")
+    _print_spans(stats)
     print(json.dumps(report, default=str))
     return report
 
@@ -154,6 +167,7 @@ def ops_demo_async(
     if stats.workers > 1:
         print(f"pool: {stats.workers} workers, {stats.steals} steals, "
               f"occupancy {[round(o, 2) for o in stats.worker_occupancy]}")
+    _print_spans(stats)
     print(json.dumps(report, default=str))
     return report
 
@@ -222,6 +236,7 @@ def decode_serve_demo(
     print(f"latency p50/p99: {stats.total_p50*1e3:.1f}/{stats.total_p99*1e3:.1f} ms; "
           f"SLO {slo_ms:.0f} ms -> {stats.slo_violations}/{stats.slo_checked} violations "
           f"(attainment {stats.slo_attainment})")
+    _print_spans(stats)
     report = {**svc.throughput_report(), "oracle_parity": parity}
     print(json.dumps(report, default=str))
     return report
