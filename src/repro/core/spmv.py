@@ -16,6 +16,14 @@ of ``grain`` rows with ``lax.map`` (sequential across chunks, vector within),
 the Pallas kernel uses it as rows-per-program, and the distributed path uses
 it as the rows-per-shard block factor.
 
+The local path holds its row chunks K-major, ``(n_chunks, P, K, grain)``,
+rows on the minor (lane) axis, and gathers ``x`` once per step over the
+whole block. K is the largest row degree (5 for the 2-D Laplacian): with K
+minor, a TPU ``(8, 128)`` tile would hold K real lanes in 128. Where K is
+longer than the chunk (thousands for an unsplit power-law matrix against a
+grain of a few rows) the chunks stay ``(n_chunks, P, grain, K)``: the
+longer axis takes the lanes (DESIGN.md §2).
+
 This module holds the *algorithm* (one function per substrate:
 :func:`spmv_local`, :func:`spmv_mesh`); substrate selection lives in
 :mod:`repro.engine` (DESIGN.md §1). :func:`spmv` is a deprecated shim.
@@ -97,37 +105,51 @@ def unstripe_vector(xs: jax.Array, n: int) -> jax.Array:
     return xs.T.reshape(p * npp)[:n]
 
 
-def _rows_kernel(cols, vals, x_full):
-    """Compute one chunk of rows: masked gather + reduce. cols/vals (..., K)."""
-    mask = cols >= 0
+def _k_major(rows: int, k: int) -> bool:
+    """Whether a block of ``rows`` ELL rows of width ``k`` is held K-major,
+    ``(..., K, rows)``: the longer of the two axes takes the minor (lane) axis.
+    On a v5e the K-major chunks ran 10% faster at K 5, grain 1024, and 5%
+    slower at K 3,626, grain 6 (PERF.md §6)."""
+    return rows >= k
+
+
+def _rows_kernel(cols, vals, x_full, k_major: bool):
+    """Compute a block of rows: one gather of ``x`` over the whole block,
+    masked where ``cols`` pads with -1, summed over the ELL columns.
+    ``cols``/``vals`` are ``(..., K, rows)`` if ``k_major`` else ``(..., rows, K)``."""
     xg = jnp.take(x_full, jnp.maximum(cols, 0), axis=0)
-    return jnp.sum(jnp.where(mask, vals * xg, 0), axis=-1)
+    return jnp.sum(jnp.where(cols >= 0, vals * xg, 0), axis=-2 if k_major else -1)
 
 
 @partial(jax.jit, static_argnames=("grain",))
 def _spmv_local(a: PartitionedELL, x_full: jax.Array, grain: int) -> jax.Array:
-    """Single-device semantics path: vmap over nodelets, lax.map over row
-    chunks of ``grain`` rows (the task structure the Emu sees)."""
+    """Single-device semantics path: ``lax.map`` over row chunks of ``grain``
+    rows (the task structure the Emu sees), all nodelets in each step. The
+    chunks are ``(n_chunks, P, K, grain)`` when ``grain >= K`` (rows on the
+    lanes), else ``(n_chunks, P, grain, K)``."""
     P, rp, k = a.cols.shape
     g = max(1, min(grain, rp))
     n_chunks = ceil_div(rp, g)
     pad = n_chunks * g - rp
-    cols = jnp.pad(a.cols, ((0, 0), (0, pad), (0, 0)), constant_values=-1)
-    vals = jnp.pad(a.vals, ((0, 0), (0, pad), (0, 0)))
-    cols = cols.reshape(P, n_chunks, g, k)
-    vals = vals.reshape(P, n_chunks, g, k)
+    k_major = _k_major(g, k)
 
-    def per_nodelet(c, v):
-        return jax.lax.map(lambda cv: _rows_kernel(cv[0], cv[1], x_full), (c, v))
+    def chunks(plane, fill):
+        plane = jnp.pad(plane, ((0, 0), (0, pad), (0, 0)), constant_values=fill)
+        if k_major:
+            return plane.transpose(0, 2, 1).reshape(P, k, n_chunks, g).transpose(2, 0, 1, 3)
+        return plane.reshape(P, n_chunks, g, k).transpose(1, 0, 2, 3)
 
-    y = jax.vmap(per_nodelet)(cols, vals)  # (P, n_chunks, g)
-    return y.reshape(P, n_chunks * g)[:, :rp]
+    y = jax.lax.map(
+        lambda cv: _rows_kernel(cv[0], cv[1], x_full, k_major),
+        (chunks(a.cols, -1), chunks(a.vals, 0)),
+    )  # (n_chunks, P, g)
+    return y.transpose(1, 0, 2).reshape(P, n_chunks * g)[:, :rp]
 
 
 def spmv_local(
     a: PartitionedELL, x: jax.Array, strategy: MigratoryStrategy
 ) -> jax.Array:
-    """``local`` substrate: single-device vmap emulation with the distributed
+    """``local`` substrate: single-device emulation with the distributed
     path's semantics. ``x``: full (N,) if ``strategy.replicate_x`` else
     striped (P, N_p). Returns y in striped (P, R_p) layout."""
     grain = strategy.dynamic_grain(a.rows_per_nodelet)
@@ -148,21 +170,22 @@ def spmv_mesh(
     from jax.sharding import PartitionSpec as P_
 
     n = a.shape[1]
+    k_major = _k_major(*a.cols.shape[1:])
+
+    def rows(cols_p, vals_p, x_full):
+        c, v = (cols_p[0].T, vals_p[0].T) if k_major else (cols_p[0], vals_p[0])
+        return _rows_kernel(c, v, x_full, k_major)[None]
 
     if strategy.replicate_x:
-
-        def body(cols_p, vals_p, x_rep):
-            # x already local everywhere: pure local compute (paper's S1 win)
-            return _rows_kernel(cols_p[0], vals_p[0], x_rep)[None]
-
+        # x already local everywhere: pure local compute (paper's S1 win)
+        body = rows
         in_specs = (P_(axis_name), P_(axis_name), P_())
     else:
 
         def body(cols_p, vals_p, x_striped):
             # migrate/pull: gather the striped vector (thread-migration analogue)
             xg = jax.lax.all_gather(x_striped, axis_name)  # (P, 1, N_p)
-            x_full = unstripe_vector(xg[:, 0, :], n)
-            return _rows_kernel(cols_p[0], vals_p[0], x_full)[None]
+            return rows(cols_p, vals_p, unstripe_vector(xg[:, 0, :], n))
 
         in_specs = (P_(axis_name), P_(axis_name), P_(axis_name))
 

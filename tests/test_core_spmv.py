@@ -11,18 +11,46 @@ from repro.core import (
 from repro.sparse import CSR, laplacian_2d, skewed_matrix, spmv_csr_ref
 
 
+def _degrees_0_to_k(k: int) -> np.ndarray:
+    """96 x 84 dense matrix whose rows take every degree 0..k, so its ELL
+    planes (K = k) hold padding slots (col -1) and empty rows."""
+    rng = np.random.default_rng(k)
+    d = np.zeros((96, 84), np.float32)
+    for r in range(96):
+        deg = r % (k + 1)
+        d[r, rng.choice(84, deg, replace=False)] = rng.standard_normal(deg)
+    return d
+
+
+MATRICES = {
+    "laplacian": lambda: np.asarray(laplacian_2d(12).to_dense()),  # 144 x 144, K = 5
+    "deg0to1": lambda: _degrees_0_to_k(1),
+    "deg0to5": lambda: _degrees_0_to_k(5),
+    "deg0to7": lambda: _degrees_0_to_k(7),
+}
+
+
+@pytest.mark.parametrize("matrix", list(MATRICES))
 @pytest.mark.parametrize("replicate", [True, False])
-@pytest.mark.parametrize("grain", [1, 4, 16, None])
-def test_spmv_strategies_match_ref(replicate, grain):
-    a = laplacian_2d(12)  # 144x144
-    n = 144
+@pytest.mark.parametrize("grain", [1, 4, 5, 16, None])
+def test_spmv_strategies_match_ref(replicate, grain, matrix):
+    """The local kernel's K-major row chunks against a float64 dense product,
+    across strategies and grains: one row per task, a grain that divides
+    R_p (12 for the 96-row matrices), a ragged last chunk, a grain above
+    R_p, and the dynamic grain."""
+    d = MATRICES[matrix]()
+    n_rows, n_cols = d.shape
     rng = np.random.default_rng(0)
-    x = jnp.asarray(rng.standard_normal(n).astype(np.float32))
-    pe = partition_ell(a, 8)
+    x = rng.standard_normal(n_cols).astype(np.float32)
+    pe = partition_ell(CSR.from_dense(d), 8)
     st_ = MigratoryStrategy(replicate_x=replicate, grain=grain)
-    xin = x if replicate else stripe_vector(x, 8)
-    y = gather_result(spmv(pe, xin, st_), n)
-    assert np.allclose(np.asarray(y), np.asarray(spmv_csr_ref(a, x)), atol=1e-4)
+    xin = jnp.asarray(x) if replicate else stripe_vector(jnp.asarray(x), 8)
+    y = np.asarray(gather_result(spmv(pe, xin, st_), n_rows))
+    ref = d.astype(np.float64) @ x.astype(np.float64)
+    # float32 sums of at most K terms: well inside 1e-6 of sum |terms|
+    bound = 1e-6 * (np.abs(d).astype(np.float64) @ np.abs(x).astype(np.float64))
+    assert np.all(np.abs(y - ref) <= bound)
+    assert np.all(y[~d.any(axis=1)] == 0)
 
 
 def test_replication_eliminates_migrations():

@@ -16,6 +16,8 @@ time on a TPU instead of interpreting them.
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and test workers import every file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -34,7 +36,7 @@ from repro.kernels.topk_sim.kernel import topk_sim_pallas
 from repro.sparse.graph import PartitionedGraph
 
 P = 8  # nodelets
-LAP_ROWS = 2048 * 2048
+LAP_ROWS, LAP_K = 2048 * 2048, 5
 BFS_SCALE, BFS_K = 21, 68
 GSANA_TASKS, GSANA_CAP, GSANA_FEATURES = 2304, 49, 5 + 16 + 16 + 64  # n=8192
 V5E_HBM_BYTES = 16 * 2**30
@@ -82,16 +84,78 @@ def _compile(fn, *args):
     return compiled
 
 
+def _compile_local_spmv(shapes, replicate_x, n=LAP_ROWS, k=LAP_K):
+    rp = -(-n // P)
+    a = PartitionedELL(
+        cols=shapes((P, rp, k), jnp.int32), vals=shapes((P, rp, k)), shape=(n, n),
+    )
+    x = shapes((n,)) if replicate_x else shapes((P, rp))
+    st = MigratoryStrategy(replicate_x=replicate_x)
+    return _compile(lambda a, x: spmv_local(a, x, st), a, x)
+
+
+def _while_body_tiles(hlo: str) -> list[tuple[tuple[int, ...], int]]:
+    """``(dims, size of the minor dim)`` of every ``T(8,128)``-tiled array in
+    the while loops' bodies of an optimized HLO module, callees included."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif line == "}":
+            name = None
+        elif name:
+            comps[name].append(line.split(", metadata=")[0])
+    todo = [b for lines in comps.values() for line in lines
+            for b in re.findall(r"\bbody=%([\w.\-]+)", line)]
+    seen = set()
+    while todo:
+        c = todo.pop()
+        if c not in seen:
+            seen.add(c)
+            todo += [m for line in comps[c]
+                     for m in re.findall(r"\b(?:calls|to_apply|body|condition)=%([\w.\-]+)", line)]
+    tiles = []
+    for c in seen:
+        for line in comps[c]:
+            for dims, layout in re.findall(r"\b[a-z]\w*\[([\d,]+)\]\{([\d,]+):T\(8,128\)", line):
+                d = tuple(int(v) for v in dims.split(","))
+                tiles.append((d, d[int(layout.split(",")[0])]))
+    return tiles
+
+
 @pytest.mark.parametrize("replicate_x", [True, False], ids=["replicated_x", "striped_x"])
 def test_local_spmv_compiles_for_v5e(shapes, replicate_x):
-    rp = LAP_ROWS // P
-    a = PartitionedELL(
-        cols=shapes((P, rp, 5), jnp.int32), vals=shapes((P, rp, 5)),
-        shape=(LAP_ROWS, LAP_ROWS),
-    )
-    x = shapes((LAP_ROWS,)) if replicate_x else shapes((P, rp))
-    st = MigratoryStrategy(replicate_x=replicate_x)
-    _compile(lambda a, x: spmv_local(a, x, st), a, x)
+    _compile_local_spmv(shapes, replicate_x)
+
+
+@pytest.mark.parametrize(
+    "n, k, grain, temp_limit",
+    [
+        # the cell's Laplacian: grain 1024 rows against K = 5 (177 MB K-minor)
+        (LAP_ROWS, LAP_K, 1024, 128 * 10**6),
+        # Table 3's Stanford at the paper's size: K = 3,860 against a grain
+        # of 6 rows; temp as with the K-minor chunks before (1,343.6 MB)
+        (28_200, 3860, 6, 1400 * 10**6),
+    ],
+    ids=["laplacian_k5", "stanford_k3860"],
+)
+def test_local_spmv_row_chunks_are_lane_dense_for_v5e(shapes, n, k, grain, temp_limit):
+    """The row-chunk loop puts the longer of K and the chunk's rows (the
+    dynamic grain) on the lanes: no array in the loop body that holds the K
+    axis has the shorter one as its minor dimension under the (8, 128)
+    tile, which pads it to 128 lanes. The loop holds one gather whatever K
+    is."""
+    compiled = _compile_local_spmv(shapes, replicate_x=True, n=n, k=k)
+    hlo = compiled.as_text()
+    tiles = _while_body_tiles(hlo)
+    assert tiles, "no while loop over row chunks in the compiled program"
+    short = min(k, grain)
+    padded = sorted({dims for dims, minor in tiles if minor == short and k in dims})
+    assert not padded, f"arrays with {short} lanes in the row-chunk loop: {padded}"
+    assert len(re.findall(r" gather\(", hlo)) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_limit
 
 
 def test_local_bfs_compiles_for_v5e(shapes):
