@@ -4,8 +4,9 @@
 - fig5_replication: same sweep with x replicated (S1)
 - fig6_scaling:     single-node (8 nodelets) vs multi-node (64) thread sweep
 - table3_realworld: degree-signature proxies of the paper's matrices,
-                    incl. the Stanford/ins2 hub pathology and the
-                    split-long-rows mitigation (paper §5.1 future work)
+                    incl. the Stanford/ins2 hub pathology padded to the
+                    longest row, and split by ``partition_ell`` where it
+                    splits (paper §5.1 future work)
 """
 from __future__ import annotations
 
@@ -14,7 +15,7 @@ import numpy as np
 
 from repro.core import MigratoryStrategy, partition_ell
 from repro.engine import SpMVInputs, SpMVOp, run as engine_run
-from repro.sparse import TABLE3_SIGNATURES, laplacian_2d, skewed_matrix, split_long_rows
+from repro.sparse import TABLE3_SIGNATURES, laplacian_2d, skewed_matrix
 
 from .util import emit_report
 
@@ -88,12 +89,11 @@ def table3_realworld(full: bool = False, quick: bool = False):
             "table3_spmv_realworld", name, rep,
             avg_deg=round(float(lens.mean()), 2), max_deg=kmax,
         ))
-        if kmax > 500:  # hub mitigation: split long rows (paper future work)
-            s, owner = split_long_rows(a, k=64)
-            inputs2 = SpMVInputs(partition_ell(s, 8, k=64), x)
-            _, rep2 = engine_run(SpMVOp(), inputs2, st, "local")
+        split = partition_ell(a, 8)
+        if split.row_of is not None:  # hub rows split into owner-local pieces
+            _, rep2 = engine_run(SpMVOp(), SpMVInputs(split, x), st, "local")
             rows.append(emit_report(
-                "table3_spmv_realworld", f"{name}+rowsplit", rep2, max_deg=64,
+                "table3_spmv_realworld", f"{name}+rowsplit", rep2, max_deg=split.k,
             ))
     return rows
 

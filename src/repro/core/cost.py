@@ -97,8 +97,9 @@ def spmv_cost_model(inputs) -> CostModel:
     n_cols = a.shape[1]
     # what one launch streams: the *padded* ELL slab (vals f32 + cols i32,
     # padding included — skewed matrices execute their padding) plus x
-    # gathered and y written; random reads dominate, so this is charged at
-    # the machine file's gather rate
+    # gathered and y written, per ELL row: where hub rows are split, R_p'
+    # rows of width K, each partial sum written and folded; random reads
+    # dominate, so this is charged at the machine file's gather rate
     sweep_bytes = cols.size * 8 + 2 * 4 * p * rp
 
     def estimate(st: MigratoryStrategy) -> CostEstimate:

@@ -24,6 +24,12 @@ longer than the chunk (thousands for an unsplit power-law matrix against a
 grain of a few rows) the chunks stay ``(n_chunks, P, grain, K)``: the
 longer axis takes the lanes (DESIGN.md §2).
 
+Where padding every row to the longest would hold more than twice the
+nonzeros (a hub row of a web graph), :func:`partition_ell` splits the long
+rows into pieces of a narrower K (:func:`ell_width`) on the row's own
+nodelet, and every substrate folds the pieces' sums back onto their rows
+with :func:`fold_pieces`, so the result keeps the ``(P, R_p)`` layout.
+
 This module holds the *algorithm* (one function per substrate:
 :func:`spmv_local`, :func:`spmv_mesh`); substrate selection lives in
 :mod:`repro.engine` (DESIGN.md §1). :func:`spmv` is a deprecated shim.
@@ -39,24 +45,32 @@ import numpy as np
 
 from ..sparse.csr import CSR
 from .strategies import MigratoryStrategy, TrafficStats
-from .util import ceil_div, round_up
+from .util import ceil_div
 
 
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass(frozen=True)
 class PartitionedELL:
-    """Per-nodelet padded ELL planes. Global row r <-> (p=r%P, slot=r//P)."""
+    """Per-nodelet padded ELL planes. Global row r <-> (p=r%P, slot=r//P).
 
-    cols: jax.Array  # (P, R_p, K) int32 global col ids, -1 pad
-    vals: jax.Array  # (P, R_p, K)
+    Without a split (``row_of`` None) ELL row i of a nodelet is its slot i.
+    With one, a row longer than K is cut into pieces of at most K nonzeros,
+    each an ELL row on the row's own nodelet, in row order, and ``row_of``
+    names the slot each ELL row adds into (R_p = ceil(N/P) for a padding
+    row, which :func:`fold_pieces` drops)."""
+
+    cols: jax.Array  # (P, R_p', K) int32 global col ids, -1 pad
+    vals: jax.Array  # (P, R_p', K)
     shape: tuple[int, int]
+    row_of: jax.Array | None = None  # (P, R_p') int32, sorted along R_p'
 
     def tree_flatten(self):
-        return (self.cols, self.vals), self.shape
+        return (self.cols, self.vals, self.row_of), self.shape
 
     @classmethod
     def tree_unflatten(cls, shape, leaves):
-        return cls(*leaves, shape=shape)
+        cols, vals, row_of = leaves
+        return cls(cols, vals, shape, row_of)
 
     @property
     def P(self) -> int:
@@ -64,6 +78,7 @@ class PartitionedELL:
 
     @property
     def rows_per_nodelet(self) -> int:
+        """ELL rows per nodelet, R_p': the rows the kernels' tasks cover."""
         return self.cols.shape[1]
 
     @property
@@ -71,25 +86,74 @@ class PartitionedELL:
         return self.cols.shape[2]
 
 
-def partition_ell(a: CSR, p: int, k: int | None = None, pad_rows_to: int = 1) -> PartitionedELL:
-    """Stripe a CSR matrix's rows over ``p`` nodelets as padded ELL planes."""
-    indptr = np.asarray(a.indptr)
+def ell_width(lens: np.ndarray, p: int) -> int:
+    """The ELL width K for rows of ``lens`` nonzeros striped over ``p``
+    nodelets, from the row-degree histogram alone (DESIGN.md §2).
+
+    The longest row, where padding every row to it holds at most twice the
+    nonzeros. Otherwise the width that minimises padded slots plus pieces:
+    a row of L nonzeros takes ceil(L / K) ELL rows, and each ELL row costs
+    its K gathered slots and one partial sum folded onto its row. The
+    gather costs per slot, padding included (PERF.md §6), so the slots,
+    not the nonzeros, set the kernel's time."""
+    lens = np.asarray(lens, dtype=np.int64)
+    kmax, nnz = int(lens.max(initial=0)), int(lens.sum())
+    if p * ceil_div(len(lens), p) * kmax <= 2 * nnz:
+        return max(kmax, 1)
+    lengths, counts = np.unique(lens[lens > 0], return_counts=True)
+    # a width above 2 nnz / rows - 1 costs more than K = 1's 2 nnz
+    widths = np.arange(1, min(kmax, 2 * nnz // int(counts.sum())) + 1)
+    ell_rows = np.array([(counts * ceil_div(lengths, w)).sum() for w in widths])
+    return int(widths[np.argmin((widths + 1) * ell_rows)])
+
+
+def partition_ell(a: CSR, p: int, k: int | None = None) -> PartitionedELL:
+    """Stripe a CSR matrix's rows over ``p`` nodelets as padded ELL planes
+    of width ``k`` (default :func:`ell_width`); rows longer than ``k`` are
+    split into owner-local pieces (:class:`PartitionedELL`)."""
+    indptr = np.asarray(a.indptr).astype(np.int64)
     indices = np.asarray(a.indices)
     data = np.asarray(a.data)
     n = a.n_rows
-    lens = indptr[1:] - indptr[:-1]
-    kmax = int(lens.max()) if n else 1
-    k = k or max(kmax, 1)
-    if kmax > k:
-        raise ValueError(f"max row degree {kmax} > k={k}; use split_long_rows first")
-    rp = round_up(ceil_div(n, p), pad_rows_to)
-    cols = np.full((p, rp, k), -1, dtype=np.int32)
-    vals = np.zeros((p, rp, k), dtype=data.dtype)
-    for r in range(n):
-        s, e = indptr[r], indptr[r + 1]
-        cols[r % p, r // p, : e - s] = indices[s:e]
-        vals[r % p, r // p, : e - s] = data[s:e]
-    return PartitionedELL(cols=jnp.asarray(cols), vals=jnp.asarray(vals), shape=a.shape)
+    lens = np.diff(indptr)
+    k = k or ell_width(lens, p)
+    split = k < lens.max(initial=0)
+    # ELL rows of each row: one for every row unsplit, empty ones too; split,
+    # ceil(L / k), none for an empty row
+    pieces = ceil_div(lens, k) if split else np.ones(n, dtype=np.int64)
+    rp = ceil_div(n, p)
+    per_slot = np.zeros(rp * p, dtype=np.int64)
+    per_slot[:n] = pieces
+    per_slot = per_slot.reshape(rp, p)  # [slot, nodelet], row r at [r // p, r % p]
+    first = (np.cumsum(per_slot, axis=0) - per_slot).reshape(-1)  # row r's first ELL row
+    rp_ell = max(int(per_slot.sum(axis=0).max()), 1) if split else rp
+    rows = np.repeat(np.arange(n), lens)  # each nonzero's row
+    pos = np.arange(len(rows)) - np.repeat(indptr[:-1], lens)  # its place in the row
+    ell_row = first[rows] + pos // k
+    cols = np.full((p, rp_ell, k), -1, dtype=np.int32)
+    vals = np.zeros((p, rp_ell, k), dtype=data.dtype)
+    cols[rows % p, ell_row, pos % k] = indices
+    vals[rows % p, ell_row, pos % k] = data
+    planes = {"cols": jnp.asarray(cols), "vals": jnp.asarray(vals), "shape": a.shape}
+    if not split:
+        return PartitionedELL(**planes)
+    owner = np.repeat(np.arange(n), pieces)  # each ELL row's row
+    piece = np.arange(len(owner)) - np.repeat(np.cumsum(pieces) - pieces, pieces)
+    row_of = np.full((p, rp_ell), rp, dtype=np.int32)
+    row_of[owner % p, first[owner] + piece] = owner // p
+    return PartitionedELL(**planes, row_of=jnp.asarray(row_of))
+
+
+def spmv_layout_counts(a: PartitionedELL) -> dict[str, int]:
+    """``spmv.slots``: the padded index slots one product gathers;
+    ``spmv.pieces``: the ELL rows the split adds, beyond one per split row."""
+    pieces = 0
+    if a.row_of is not None:
+        row_of = np.asarray(a.row_of)
+        used = row_of < ceil_div(a.shape[0], a.P)
+        first = np.diff(row_of, axis=1, prepend=-1) != 0
+        pieces = int(used.sum() - (used & first).sum())
+    return {"spmv.slots": int(a.cols.size), "spmv.pieces": pieces}
 
 
 def stripe_vector(x: jax.Array, p: int) -> jax.Array:
@@ -111,6 +175,18 @@ def _k_major(rows: int, k: int) -> bool:
     On a v5e the K-major chunks ran 10% faster at K 5, grain 1024, and 5%
     slower at K 3,626, grain 6 (PERF.md §6)."""
     return rows >= k
+
+
+def fold_pieces(y: jax.Array, row_of: "jax.Array | None", rows: int) -> jax.Array:
+    """Add each ELL row's sum onto the slot ``row_of`` names: ``(P, R_p')``
+    -> ``(P, rows)``; a slot past ``rows`` (padding) is dropped. Without a
+    split (``row_of`` None) ``y`` is already the result and nothing is added
+    to the program."""
+    if row_of is None:
+        return y
+    return jax.vmap(
+        lambda yp, ids: jax.ops.segment_sum(yp, ids, num_segments=rows, indices_are_sorted=True)
+    )(y, row_of)
 
 
 def _rows_kernel(cols, vals, x_full, k_major: bool):
@@ -143,7 +219,8 @@ def _spmv_local(a: PartitionedELL, x_full: jax.Array, grain: int) -> jax.Array:
         lambda cv: _rows_kernel(cv[0], cv[1], x_full, k_major),
         (chunks(a.cols, -1), chunks(a.vals, 0)),
     )  # (n_chunks, P, g)
-    return y.transpose(1, 0, 2).reshape(P, n_chunks * g)[:, :rp]
+    y = y.transpose(1, 0, 2).reshape(P, n_chunks * g)[:, :rp]
+    return fold_pieces(y, a.row_of, ceil_div(a.shape[0], P))
 
 
 def spmv_local(
@@ -171,29 +248,30 @@ def spmv_mesh(
 
     n = a.shape[1]
     k_major = _k_major(*a.cols.shape[1:])
+    rows_out = ceil_div(a.shape[0], a.P)
 
-    def rows(cols_p, vals_p, x_full):
-        c, v = (cols_p[0].T, vals_p[0].T) if k_major else (cols_p[0], vals_p[0])
-        return _rows_kernel(c, v, x_full, k_major)[None]
+    def rows(a_p: PartitionedELL, x_full):
+        c, v = (a_p.cols[0].T, a_p.vals[0].T) if k_major else (a_p.cols[0], a_p.vals[0])
+        return fold_pieces(_rows_kernel(c, v, x_full, k_major)[None], a_p.row_of, rows_out)
 
     if strategy.replicate_x:
         # x already local everywhere: pure local compute (paper's S1 win)
         body = rows
-        in_specs = (P_(axis_name), P_(axis_name), P_())
+        in_specs = (P_(axis_name), P_())
     else:
 
-        def body(cols_p, vals_p, x_striped):
+        def body(a_p, x_striped):
             # migrate/pull: gather the striped vector (thread-migration analogue)
             xg = jax.lax.all_gather(x_striped, axis_name)  # (P, 1, N_p)
-            return rows(cols_p, vals_p, unstripe_vector(xg[:, 0, :], n))
+            return rows(a_p, unstripe_vector(xg[:, 0, :], n))
 
-        in_specs = (P_(axis_name), P_(axis_name), P_(axis_name))
+        in_specs = (P_(axis_name), P_(axis_name))
 
     f = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=P_(axis_name),
         check_vma=False,
     )
-    return f(a.cols, a.vals, x)
+    return f(a, x)
 
 
 def spmv(

@@ -111,7 +111,11 @@ class ExecutionPlan:
 
 @runtime_checkable
 class MigratoryOp(Protocol):
-    """A distributed operation the engine knows how to run and account for."""
+    """A distributed operation the engine knows how to run and account for.
+
+    An op may also define ``counters(plan) -> dict[str, int]``: per-request
+    counts read from the plan (``SpMVOp``: ``spmv.slots``, ``spmv.pieces``),
+    which the runner adds to the service's span totals."""
 
     name: str
 

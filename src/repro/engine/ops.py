@@ -36,6 +36,7 @@ from ..core.gsana_data import Buckets, VertexSet
 from ..core.spmv import (
     PartitionedELL,
     spmv_bytes_moved,
+    spmv_layout_counts,
     spmv_traffic,
     stripe_vector,
 )
@@ -146,6 +147,12 @@ class SpMVOp:
             "grain": plan.strategy.dynamic_grain(plan.inputs.a.rows_per_nodelet),
             "nodelets": plan.inputs.a.P,
         }
+
+    def counters(self, plan: ExecutionPlan) -> dict[str, int]:
+        """``spmv.slots`` and ``spmv.pieces`` of the plan's layout, read once
+        per matrix: every request that multiplies by it shares them."""
+        a = plan.inputs.a
+        return _derived_cached("spmv_layout", a, None, lambda: spmv_layout_counts(a))
 
 
 # -- BFS -----------------------------------------------------------------------

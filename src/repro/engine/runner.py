@@ -15,7 +15,9 @@ The stages are individually exposed (DESIGN.md §1):
 
 Each call opens the spans ``engine.dispatch`` (until the executor returns
 unready arrays) and ``engine.device`` (``block_until_ready``), and the
-report's derived stats run under ``engine.derived`` (:mod:`.spans`).
+report's derived stats run under ``engine.derived`` (:mod:`.spans`), which
+also adds the op's per-request counters (``SpMVOp.counters``) to the
+request's span totals.
 """
 from __future__ import annotations
 
@@ -29,7 +31,7 @@ from .api import ExecutionPlan, MigratoryOp, RunReport
 from .cache import CompiledPlan, PlanCache, default_cache
 from .registry import default_registry
 from .request import Request, coerce_request
-from .spans import DERIVED, DEVICE, DISPATCH, span
+from .spans import DERIVED, DEVICE, DISPATCH, count, span
 from .substrate import Substrate, get_substrate
 
 
@@ -198,7 +200,8 @@ def run_plan(
     slot: "int | None" = None,
 ) -> tuple[Any, RunReport]:
     """Compile + execute an already-built plan and assemble its RunReport;
-    the derived stats run under the span ``engine.derived``."""
+    the derived stats, and the op's ``counters`` where it has them, run
+    under the span ``engine.derived``."""
     compiled, result, seconds, compile_seconds = _execute(plan, iters, warmup, cache, slot)
     # model honesty columns (DESIGN.md §1f): only a *calibrated* machine
     # file produces predictions — without one the report is bit-identical
@@ -220,6 +223,8 @@ def run_plan(
             compile_seconds=compile_seconds,
             predicted_seconds=predicted,
         )
+        if hasattr(op, "counters"):
+            count(op.counters(plan))
     return result, report
 
 

@@ -319,6 +319,9 @@ class ServiceStats:
       on the warm path). The timings above take their clock readings from
       these spans: queue wait is admission -> ``engine.run`` start, service
       time ``engine.run`` start -> ``engine.resolve`` start.
+    - ``counters`` — per-request counts the ops add under ``engine.derived``,
+      summed: ``spmv.slots`` (padded index slots gathered) and
+      ``spmv.pieces`` (ELL rows added by splitting long rows).
     """
 
     requests: int = 0
@@ -372,6 +375,7 @@ class ServiceStats:
     span_counts: "dict[str, int]" = dataclasses.field(default_factory=dict)
     xla_compiles: "dict[str, int]" = dataclasses.field(default_factory=dict)
     xla_compile_seconds: "dict[str, float]" = dataclasses.field(default_factory=dict)
+    counters: "dict[str, int]" = dataclasses.field(default_factory=dict)
 
     @property
     def requests_per_second(self) -> float:
@@ -464,6 +468,7 @@ class ServiceStats:
             "span_counts": self.span_counts,
             "xla_compiles": self.xla_compiles,
             "xla_compile_seconds": self.xla_compile_seconds,
+            "counters": self.counters,
             "resize_signal": self.resize_signal(),
             "requests_per_second": self.requests_per_second,
             "amortization": self.amortization,

@@ -14,6 +14,10 @@ runner's spans (``engine.dispatch`` ... ``engine.derived``) join the
 request that opened them. Spans are always on: with the profiler off a
 span costs a few microseconds, against a served call of milliseconds.
 
+Counters (:func:`count`) add whole numbers to the same totals, under the
+innermost open span's owner: ``spmv.slots`` and ``spmv.pieces`` per served
+SpMV, from its plan's layout.
+
 XLA compiles (JAX's ``/jax/core/compile/backend_compile_duration`` event,
 persistent-cache reads included) are counted in the same totals, under the
 outermost open span of the compiling thread: the pipeline stage.
@@ -81,6 +85,7 @@ class SpanTotals:
         self._counts: collections.Counter = collections.Counter()
         self._compiles: collections.Counter = collections.Counter()
         self._compile_seconds: collections.Counter = collections.Counter()
+        self._counters: collections.Counter = collections.Counter()
 
     def add(self, name: str, seconds: float) -> None:
         with self._lock:
@@ -92,6 +97,10 @@ class SpanTotals:
             self._compiles[stage] += 1
             self._compile_seconds[stage] += seconds
 
+    def add_counts(self, counters: "dict[str, int]") -> None:
+        with self._lock:
+            self._counters.update(counters)
+
     def snapshot(self) -> dict:
         with self._lock:
             return {
@@ -99,7 +108,16 @@ class SpanTotals:
                 "span_counts": dict(self._counts),
                 "xla_compiles": dict(self._compiles),
                 "xla_compile_seconds": dict(self._compile_seconds),
+                "counters": dict(self._counters),
             }
+
+
+def count(counters: "dict[str, int]") -> None:
+    """Add ``counters`` to the totals of this thread's innermost open span
+    (on the served path, the request's); outside a span, nothing."""
+    stack = getattr(_open, "stack", None)
+    if stack and stack[-1].totals is not None:
+        stack[-1].totals.add_counts(counters)
 
 
 class span:

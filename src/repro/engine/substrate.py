@@ -32,8 +32,9 @@ import jax
 
 from ..core.bfs import bfs_local, bfs_mesh
 from ..core.gsana import NEG, compute_similarity, compute_similarity_mesh
-from ..core.spmv import spmv_local, spmv_mesh, unstripe_vector
+from ..core.spmv import fold_pieces, spmv_local, spmv_mesh, unstripe_vector
 from ..core.strategies import MigratoryStrategy, Scheme
+from ..core.util import ceil_div
 from .api import OpNotSupportedError
 from .registry import default_registry, kernel
 
@@ -360,12 +361,12 @@ def _spmv_pallas(sub: PallasSubstrate, a, x, *, strategy):
     x_full = x if strategy.replicate_x else unstripe_vector(x, a.shape[1])
     p, rp, k = a.cols.shape
     grain = strategy.dynamic_grain(rp)
-    # nodelet planes -> one (P*R_p, K) row block; kernel grid = row chunks
+    # nodelet planes -> one (P*R_p', K) row block; kernel grid = row chunks
     y = spmv_kernel(
         a.cols.reshape(p * rp, k), a.vals.reshape(p * rp, k), x_full,
         grain=max(1, min(grain, p * rp)), interpret=sub.interpret,
     )
-    return y.reshape(p, rp)
+    return fold_pieces(y.reshape(p, rp), a.row_of, ceil_div(a.shape[0], p))
 
 
 @kernel("bfs", "pallas")

@@ -74,8 +74,9 @@ def _ops_workload(shapes: tuple[int, ...], seed: int):
 
 
 def _print_spans(stats) -> None:
-    """The engine's span totals (mean ms per span, count) and the XLA
-    compiles per pipeline stage: the operator's view of the served path."""
+    """The engine's span totals (mean ms per span, count), the XLA compiles
+    per pipeline stage and the per-request counters: the operator's view of
+    the served path."""
     spans = ", ".join(
         f"{name} {stats.span_seconds[name] / count * 1e3:.2f} ms x{count}"
         for name, count in stats.span_counts.items()
@@ -83,6 +84,7 @@ def _print_spans(stats) -> None:
     print(f"spans: {spans}")
     print(f"xla compiles by stage: {stats.xla_compiles} "
           f"({sum(stats.xla_compile_seconds.values())*1e3:.0f} ms)")
+    print(f"counters: {stats.counters}")
 
 def ops_demo(n_requests: int, shapes: tuple[int, ...] = (16, 24), seed: int = 0) -> dict:
     """Serve a mixed irregular-op workload through the batched EngineService.

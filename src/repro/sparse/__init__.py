@@ -1,5 +1,5 @@
 from .csr import CSR, spmv_csr_ref
-from .ell import ELL, ell_from_csr, spmv_ell_ref, split_long_rows
+from .ell import ELL, ell_from_csr, spmv_ell_ref
 from .gen import (
     TABLE3_SIGNATURES,
     edges_to_csr,
@@ -15,5 +15,4 @@ __all__ = [
     "edges_to_csr", "ell_from_csr", "erdos_renyi_edges", "global_id",
     "laplacian_2d", "local_slot", "owner_of", "partition_graph",
     "rmat_edges", "skewed_matrix", "spmv_csr_ref", "spmv_ell_ref",
-    "split_long_rows",
 ]

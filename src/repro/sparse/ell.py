@@ -77,38 +77,3 @@ def spmv_ell_ref(a: ELL, x: jax.Array) -> jax.Array:
     xg = jnp.take(x, jnp.maximum(a.cols, 0), axis=0)
     y = jnp.sum(jnp.where(mask, a.vals * xg, 0), axis=1)
     return y[: a.n_rows]
-
-
-def split_long_rows(a: CSR, k: int) -> tuple[CSR, np.ndarray]:
-    """Split rows with degree > k into chains of sub-rows (vertex-delegate
-    style mitigation for Table 3's high-max-degree pathology, §5.1).
-
-    Returns the split CSR and an int32 map ``sub_row -> original_row`` so the
-    caller can segment-sum sub-row results back together.
-    """
-    indptr = np.asarray(a.indptr)
-    indices = np.asarray(a.indices)
-    data = np.asarray(a.data)
-    new_rows, owner = [], []
-    for r in range(a.n_rows):
-        s, e = int(indptr[r]), int(indptr[r + 1])
-        if e - s <= k:
-            new_rows.append((s, e))
-            owner.append(r)
-        else:
-            for off in range(s, e, k):
-                new_rows.append((off, min(off + k, e)))
-                owner.append(r)
-    nip = np.zeros(len(new_rows) + 1, dtype=np.int64)
-    chunks_i, chunks_d = [], []
-    for i, (s, e) in enumerate(new_rows):
-        nip[i + 1] = nip[i] + (e - s)
-        chunks_i.append(indices[s:e])
-        chunks_d.append(data[s:e])
-    out = CSR(
-        indptr=jnp.asarray(nip, dtype=jnp.int32),
-        indices=jnp.asarray(np.concatenate(chunks_i) if chunks_i else np.zeros(0, np.int32)),
-        data=jnp.asarray(np.concatenate(chunks_d) if chunks_d else np.zeros(0, data.dtype)),
-        shape=(len(new_rows), a.n_cols),
-    )
-    return out, np.asarray(owner, dtype=np.int32)

@@ -106,3 +106,153 @@ def test_property_spmv_invariant_to_strategy(n, p, density, replicate, seed):
     xin = x if replicate else stripe_vector(x, p)
     y = gather_result(spmv(pe, xin, st_), n)
     assert np.allclose(np.asarray(y), d @ np.asarray(x), atol=1e-3)
+
+
+# -- rows split into owner-local pieces ----------------------------------------
+
+
+def _hub_matrix(seed: int) -> np.ndarray:
+    """Rows of every length from 0 to 60 among 200 rows of one or two
+    nonzeros, shuffled: padding to the longest row would hold about 20
+    times the nonzeros, so the layout splits at a K far below 60."""
+    rng = np.random.default_rng(seed)
+    lens = rng.permutation(np.concatenate([np.arange(61), rng.integers(1, 3, 200)]))
+    d = np.zeros((len(lens), 96), np.float32)
+    for r, deg in enumerate(lens):
+        d[r, rng.choice(96, deg, replace=False)] = rng.standard_normal(deg)
+    return d
+
+
+def _lens(d: np.ndarray) -> np.ndarray:
+    return (d != 0).sum(axis=1)
+
+
+def _served(pe, x: np.ndarray, strategy, substrate: str = "local") -> np.ndarray:
+    from repro.engine import Request, SpMVInputs, SpMVOp, run
+
+    xin = jnp.asarray(x)
+    y, _ = run(Request(SpMVOp(), SpMVInputs(pe, xin), strategy, substrate), iters=1, warmup=0)
+    return np.asarray(gather_result(y, pe.shape[0]))
+
+
+def _assert_matches_ref(d: np.ndarray, x: np.ndarray, y: np.ndarray) -> None:
+    """Under ``test_spmv_strategies_match_ref``'s bound."""
+    ref = d.astype(np.float64) @ x.astype(np.float64)
+    bound = 1e-6 * (np.abs(d).astype(np.float64) @ np.abs(x).astype(np.float64))
+    assert np.all(np.abs(y - ref) <= bound)
+    assert np.all(y[~d.any(axis=1)] == 0)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("replicate", [True, False])
+@pytest.mark.parametrize("grain", [1, 7, None])
+def test_hub_rows_split_and_served_match_ref(seed, replicate, grain):
+    """A matrix whose rows take every length from 0 to several times the
+    chosen K, served through ``Request`` on ``local``, against a float64
+    dense product."""
+    d = _hub_matrix(seed)
+    pe = partition_ell(CSR.from_dense(d), 8)
+    assert pe.row_of is not None and _lens(d).max() >= 4 * pe.k
+    x = np.random.default_rng(seed + 10).standard_normal(d.shape[1]).astype(np.float32)
+    _assert_matches_ref(d, x, _served(pe, x, MigratoryStrategy(replicate_x=replicate, grain=grain)))
+
+
+def _planes_row_by_row(d: np.ndarray, p: int, k: int):
+    """The unsplit planes as the layout step built them one row at a time."""
+    n = d.shape[0]
+    rp = -(-n // p)
+    cols = np.full((p, rp, k), -1, np.int32)
+    vals = np.zeros((p, rp, k), np.float32)
+    for r in range(n):
+        (nz,) = np.nonzero(d[r])
+        cols[r % p, r // p, : len(nz)] = nz
+        vals[r % p, r // p, : len(nz)] = d[r, nz]
+    return cols, vals
+
+
+@pytest.mark.parametrize("matrix", list(MATRICES))
+def test_unsplit_layout_has_no_row_map_and_the_same_planes(matrix):
+    """Padding to the longest row holds at most twice the nonzeros in each
+    of these (the Laplacian: 720 slots for 672 nonzeros; the 0-to-k
+    matrices exactly twice), so their layout is today's, bit for bit."""
+    d = MATRICES[matrix]()
+    pe = partition_ell(CSR.from_dense(d), 8)
+    assert pe.row_of is None and pe.k == max(_lens(d).max(), 1)
+    cols, vals = _planes_row_by_row(d, 8, pe.k)
+    assert np.array_equal(np.asarray(pe.cols), cols)
+    assert np.array_equal(np.asarray(pe.vals), vals)
+
+
+def test_ell_width_minimises_padded_slots_plus_pieces():
+    from repro.core.spmv import ell_width
+
+    lens = _lens(_hub_matrix(0))
+    nonempty = int((lens > 0).sum())
+
+    def cost(k):
+        ell_rows = int(np.ceil(lens / k).sum())
+        return k * ell_rows + ell_rows - nonempty
+
+    assert ell_width(lens, 8) == min(range(1, lens.max() + 1), key=cost)
+    assert ell_width(np.full(64, 5), 8) == 5  # the Laplacian's interior rows
+    assert ell_width(np.array([3] + [1] * 99), 1) == 1  # 300 slots for 102 nonzeros
+
+
+@pytest.mark.parametrize("k", [1, 4, 13])
+def test_explicit_k_below_the_longest_row_splits_at_k(k):
+    """Where the layout step once raised, it now splits at ``k``."""
+    d = _hub_matrix(3)
+    pe = partition_ell(CSR.from_dense(d), 4, k=k)
+    assert pe.k == k and pe.row_of is not None
+    assert pe.rows_per_nodelet == max(
+        int(np.ceil(_lens(d)[q::4] / k).sum()) for q in range(4)
+    )
+    x = np.random.default_rng(k).standard_normal(d.shape[1]).astype(np.float32)
+    _assert_matches_ref(d, x, _served(pe, x, MigratoryStrategy()))
+
+
+def test_layout_counts_are_the_layouts_own():
+    from repro.core.spmv import spmv_layout_counts
+
+    d = _hub_matrix(4)
+    pe = partition_ell(CSR.from_dense(d), 8)
+    pieces = int(np.maximum(np.ceil(_lens(d) / pe.k) - 1, 0).sum())
+    assert spmv_layout_counts(pe) == {"spmv.slots": pe.cols.size, "spmv.pieces": pieces}
+    lap = partition_ell(laplacian_2d(12), 8)
+    assert spmv_layout_counts(lap) == {"spmv.slots": 8 * 18 * 5, "spmv.pieces": 0}
+
+
+def test_service_counts_slots_and_pieces_per_request():
+    """``spmv.slots`` and ``spmv.pieces`` join the service's span totals
+    once per served request, from the plan's layout."""
+    from repro.core.spmv import spmv_layout_counts
+    from repro.engine import EngineService, Request, SpMVInputs, SpMVOp
+
+    pe = partition_ell(CSR.from_dense(_hub_matrix(5)), 8)
+    x = jnp.ones(pe.shape[1], jnp.float32)
+    with EngineService() as svc:
+        for _ in range(3):
+            svc.submit(Request(SpMVOp(), SpMVInputs(pe, x))).result()
+        stats = svc.stats()
+    counts = spmv_layout_counts(pe)
+    assert counts["spmv.pieces"] > 0
+    assert stats.counters == {name: 3 * v for name, v in counts.items()}
+    assert stats.to_dict()["counters"] == stats.counters
+
+
+def test_pallas_substrate_folds_the_pieces():
+    d = _hub_matrix(6)
+    pe = partition_ell(CSR.from_dense(d), 8)
+    assert pe.row_of is not None
+    x = np.random.default_rng(6).standard_normal(d.shape[1]).astype(np.float32)
+    _assert_matches_ref(d, x, _served(pe, x, MigratoryStrategy(grain=64), "pallas"))
+
+
+def test_plan_key_covers_the_split():
+    from repro.engine import SpMVInputs, SpMVOp, build_plan
+
+    a = CSR.from_dense(_hub_matrix(7))
+    x = jnp.ones(a.shape[1], jnp.float32)
+    split = build_plan(SpMVOp(), SpMVInputs(partition_ell(a, 8), x))
+    padded = build_plan(SpMVOp(), SpMVInputs(partition_ell(a, 8, k=60), x))
+    assert split.key != padded.key

@@ -239,11 +239,14 @@ from repro.core import Comm, MigratoryStrategy, Scheme, bucketize, \
 from repro.engine import (BFSInputs, BFSOp, GSANAInputs, GSANAOp, SpMVInputs,
                           SpMVOp, run)
 from repro.sparse import edges_to_csr, erdos_renyi_edges, laplacian_2d, \
-    partition_graph
+    partition_graph, skewed_matrix
 
 a = laplacian_2d(16)
 x = jnp.asarray(np.random.default_rng(0).standard_normal(256).astype(np.float32))
 si = SpMVInputs(partition_ell(a, 8), x)
+# hub rows split into owner-local pieces, folded back by both substrates
+sh = SpMVInputs(partition_ell(skewed_matrix(256, 6.0, 120, seed=3), 8), x)
+assert sh.a.row_of is not None
 g = edges_to_csr(erdos_renyi_edges(9, 8, seed=1), 512)
 bi = BFSInputs(partition_graph(g, 8), 3)
 vs1, vs2, pi = generate_alignment_pair(384, seed=11)
@@ -260,6 +263,9 @@ for replicate in (True, False):
         ym, rm = run(SpMVOp(), si, st, "mesh")
         assert np.array_equal(np.asarray(yl), np.asarray(ym)), ("spmv", replicate, comm)
         assert rl.traffic.migrations == rm.traffic.migrations
+        hl, _ = run(SpMVOp(), sh, st, "local")
+        hm, _ = run(SpMVOp(), sh, st, "mesh")
+        assert np.array_equal(np.asarray(hl), np.asarray(hm)), ("hub spmv", replicate, comm)
 
         pl, _ = run(BFSOp(), bi, st, "local")
         pm, _ = run(BFSOp(), bi, st, "mesh")
@@ -278,7 +284,8 @@ print("ENGINE-PARITY-OK")
 @pytest.mark.slow
 def test_local_mesh_parity_subprocess():
     """ISSUE acceptance: local and mesh substrates produce bit-identical
-    results for SpMV/BFS/GSANA across the strategy grid."""
+    results for SpMV (the Laplacian and a matrix whose hub rows are split)
+    /BFS/GSANA across the strategy grid."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     r = subprocess.run(

@@ -7,7 +7,6 @@ from hypothesis_compat import given, settings, st
 from repro.sparse import (
     CSR, ELL, edges_to_csr, ell_from_csr, erdos_renyi_edges, laplacian_2d,
     partition_graph, rmat_edges, skewed_matrix, spmv_csr_ref, spmv_ell_ref,
-    split_long_rows,
 )
 
 
@@ -34,20 +33,6 @@ def test_ell_matches_csr():
     e = ell_from_csr(a)
     x = jnp.arange(36, dtype=jnp.float32)
     assert np.allclose(np.asarray(spmv_ell_ref(e, x)), np.asarray(spmv_csr_ref(a, x)))
-
-
-def test_split_long_rows():
-    rng = np.random.default_rng(1)
-    d = np.zeros((10, 40), np.float32)
-    d[3, :37] = rng.standard_normal(37)  # hub row
-    d[5, :4] = 1.0
-    a = CSR.from_dense(d)
-    s, owner = split_long_rows(a, k=8)
-    x = jnp.asarray(rng.standard_normal(40).astype(np.float32))
-    y_sub = spmv_csr_ref(s, x)
-    y = np.zeros(10, np.float32)
-    np.add.at(y, owner, np.asarray(y_sub))
-    assert np.allclose(y, np.asarray(spmv_csr_ref(a, x)), atol=1e-5)
 
 
 def test_generators_shapes():

@@ -84,13 +84,18 @@ def _compile(fn, *args):
     return compiled
 
 
-def _compile_local_spmv(shapes, replicate_x, n=LAP_ROWS, k=LAP_K):
+def _compile_local_spmv(shapes, replicate_x, n=LAP_ROWS, k=LAP_K, ell_rows=None, grain=None):
+    """``ell_rows``: R_p' of a layout whose long rows are split, with its
+    row map; None for one ELL row per row. ``grain`` None is the dynamic
+    grain."""
     rp = -(-n // P)
+    rows = ell_rows or rp
     a = PartitionedELL(
-        cols=shapes((P, rp, k), jnp.int32), vals=shapes((P, rp, k)), shape=(n, n),
+        cols=shapes((P, rows, k), jnp.int32), vals=shapes((P, rows, k)), shape=(n, n),
+        row_of=shapes((P, rows), jnp.int32) if ell_rows else None,
     )
     x = shapes((n,)) if replicate_x else shapes((P, rp))
-    st = MigratoryStrategy(replicate_x=replicate_x)
+    st = MigratoryStrategy(replicate_x=replicate_x, grain=grain)
     return _compile(lambda a, x: spmv_local(a, x, st), a, x)
 
 
@@ -135,8 +140,9 @@ def test_local_spmv_compiles_for_v5e(shapes, replicate_x):
     [
         # the cell's Laplacian: grain 1024 rows against K = 5 (177 MB K-minor)
         (LAP_ROWS, LAP_K, 1024, 128 * 10**6),
-        # Table 3's Stanford at the paper's size: K = 3,860 against a grain
-        # of 6 rows; temp as with the K-minor chunks before (1,343.6 MB)
+        # a tenth of Table 3's Stanford (n 28,200), unsplit: K = 3,860
+        # against a grain of 6 rows; temp as with the K-minor chunks before
+        # (1,343.6 MB)
         (28_200, 3860, 6, 1400 * 10**6),
     ],
     ids=["laplacian_k5", "stanford_k3860"],
@@ -156,6 +162,28 @@ def test_local_spmv_row_chunks_are_lane_dense_for_v5e(shapes, n, k, grain, temp_
     assert not padded, f"arrays with {short} lanes in the row-chunk loop: {padded}"
     assert len(re.findall(r" gather\(", hlo)) == 1
     assert compiled.memory_analysis().temp_size_in_bytes < temp_limit
+
+
+# Table 3's Stanford at the paper's size as the cell spmv.skewed.stanford
+# lays it out and serves it: hub rows split at K = 3 into 139,107 ELL rows
+# per nodelet, 2,048 of them per task
+STANFORD_ROWS, STANFORD_ELL_ROWS, STANFORD_K, STANFORD_GRAIN = 281_903, 139_107, 3, 2048
+
+
+@pytest.mark.parametrize("split", [True, False], ids=["stanford_split", "laplacian"])
+def test_local_spmv_folds_pieces_only_where_rows_are_split(shapes, split):
+    """The split layout compiles for a v5e with one gather in the row-chunk
+    loop and one scatter-add folding the pieces onto their rows, in bounded
+    temp; a layout without a split (the Laplacian) compiles no fold."""
+    if split:
+        compiled = _compile_local_spmv(shapes, True, n=STANFORD_ROWS, k=STANFORD_K,
+                                       ell_rows=STANFORD_ELL_ROWS, grain=STANFORD_GRAIN)
+    else:
+        compiled = _compile_local_spmv(shapes, True)
+    hlo = compiled.as_text()
+    assert len(re.findall(r" gather\(", hlo)) == 1
+    assert len(re.findall(r" scatter\(", hlo)) == int(split)
+    assert compiled.memory_analysis().temp_size_in_bytes < 128 * 10**6
 
 
 def test_local_bfs_compiles_for_v5e(shapes):

@@ -201,6 +201,22 @@ def test_request_roundtrip_and_execution_parity(idx):
     np.testing.assert_array_equal(np.asarray(y0), np.asarray(y1))
 
 
+def test_split_spmv_matrix_roundtrips_and_runs():
+    """A layout with hub rows split keeps its row map across the wire."""
+    from repro.sparse import skewed_matrix
+
+    a = partition_ell(skewed_matrix(256, 6.0, 120, seed=3), 4)
+    assert a.row_of is not None
+    x = jnp.asarray(np.random.default_rng(1).standard_normal(256).astype(np.float32))
+    request = Request("spmv", SpMVInputs(a, x), MigratoryStrategy(), "local")
+    rebuilt = Request.from_wire(json.loads(json.dumps(request.to_wire())))
+    np.testing.assert_array_equal(np.asarray(rebuilt.inputs.a.row_of), np.asarray(a.row_of))
+    assert rebuilt.inputs.a.shape == a.shape
+    y0, _ = run(request, iters=1, warmup=0)
+    y1, _ = run(rebuilt, iters=1, warmup=0)
+    np.testing.assert_array_equal(np.asarray(y0), np.asarray(y1))
+
+
 def test_request_wire_version_checked():
     payload = _mixed_requests()[0].to_wire()
     payload["v"] = 999
