@@ -53,24 +53,26 @@ from .util import ceil_div
 class PartitionedELL:
     """Per-nodelet padded ELL planes. Global row r <-> (p=r%P, slot=r//P).
 
-    Without a split (``row_of`` None) ELL row i of a nodelet is its slot i.
-    With one, a row longer than K is cut into pieces of at most K nonzeros,
-    each an ELL row on the row's own nodelet, in row order, and ``row_of``
-    names the slot each ELL row adds into (R_p = ceil(N/P) for a padding
-    row, which :func:`fold_pieces` drops)."""
+    Without a split (``row_of`` and ``last_ell`` None) ELL row i of a
+    nodelet is its slot i. With one, a row longer than K is cut into pieces
+    of at most K nonzeros, each an ELL row on the row's own nodelet, in row
+    order; ``row_of`` names the slot each ELL row adds into (R_p = ceil(N/P)
+    for a padding row), and ``last_ell`` each slot's last ELL row (-1 for an
+    empty row or a padding slot), where :func:`fold_pieces` reads its sum."""
 
     cols: jax.Array  # (P, R_p', K) int32 global col ids, -1 pad
     vals: jax.Array  # (P, R_p', K)
     shape: tuple[int, int]
     row_of: jax.Array | None = None  # (P, R_p') int32, sorted along R_p'
+    last_ell: jax.Array | None = None  # (P, R_p) int32, -1 for no ELL row
 
     def tree_flatten(self):
-        return (self.cols, self.vals, self.row_of), self.shape
+        return (self.cols, self.vals, self.row_of, self.last_ell), self.shape
 
     @classmethod
     def tree_unflatten(cls, shape, leaves):
-        cols, vals, row_of = leaves
-        return cls(cols, vals, shape, row_of)
+        cols, vals, row_of, last_ell = leaves
+        return cls(cols, vals, shape, row_of, last_ell)
 
     @property
     def P(self) -> int:
@@ -141,7 +143,10 @@ def partition_ell(a: CSR, p: int, k: int | None = None) -> PartitionedELL:
     piece = np.arange(len(owner)) - np.repeat(np.cumsum(pieces) - pieces, pieces)
     row_of = np.full((p, rp_ell), rp, dtype=np.int32)
     row_of[owner % p, first[owner] + piece] = owner // p
-    return PartitionedELL(**planes, row_of=jnp.asarray(row_of))
+    (held,) = np.nonzero(pieces)
+    last_ell = np.full((p, rp), -1, dtype=np.int32)
+    last_ell[held % p, held // p] = first[held] + pieces[held] - 1
+    return PartitionedELL(**planes, row_of=jnp.asarray(row_of), last_ell=jnp.asarray(last_ell))
 
 
 def spmv_layout_counts(a: PartitionedELL) -> dict[str, int]:
@@ -149,10 +154,8 @@ def spmv_layout_counts(a: PartitionedELL) -> dict[str, int]:
     ``spmv.pieces``: the ELL rows the split adds, beyond one per split row."""
     pieces = 0
     if a.row_of is not None:
-        row_of = np.asarray(a.row_of)
-        used = row_of < ceil_div(a.shape[0], a.P)
-        first = np.diff(row_of, axis=1, prepend=-1) != 0
-        pieces = int(used.sum() - (used & first).sum())
+        used = int((np.asarray(a.row_of) < ceil_div(a.shape[0], a.P)).sum())
+        pieces = used - int((np.asarray(a.last_ell) >= 0).sum())
     return {"spmv.slots": int(a.cols.size), "spmv.pieces": pieces}
 
 
@@ -177,16 +180,28 @@ def _k_major(rows: int, k: int) -> bool:
     return rows >= k
 
 
-def fold_pieces(y: jax.Array, row_of: "jax.Array | None", rows: int) -> jax.Array:
-    """Add each ELL row's sum onto the slot ``row_of`` names: ``(P, R_p')``
-    -> ``(P, rows)``; a slot past ``rows`` (padding) is dropped. Without a
-    split (``row_of`` None) ``y`` is already the result and nothing is added
-    to the program."""
-    if row_of is None:
+def fold_pieces(y: jax.Array, a: PartitionedELL) -> jax.Array:
+    """Add each ELL row's sum ``y`` ``(P, R_p')`` onto the slot
+    ``a.row_of`` names: ``(P, R_p)``. Without a split (``row_of`` None)
+    ``y`` is already the result and nothing is added to the program.
+
+    A row's pieces are contiguous and in row order, so its sum is a
+    segmented inclusive scan along R_p' read at its last ELL row
+    (``a.last_ell``): ceil(log2 R_p') Hillis-Steele steps, each adding the
+    sum ``s`` ELL rows back where ``row_of`` equals there (``row_of`` is
+    sorted, so equal means the same row), then one gather. XLA lowers a
+    segment sum to a scatter-add, which cost 9.25 ms a product on a v5e at
+    Table 3's Stanford (PERF.md §5)."""
+    if a.row_of is None:
         return y
-    return jax.vmap(
-        lambda yp, ids: jax.ops.segment_sum(yp, ids, num_segments=rows, indices_are_sorted=True)
-    )(y, row_of)
+    row_of, s = a.row_of, 1
+    while s < y.shape[1]:
+        back = ((0, 0), (s, 0))
+        same = jnp.pad(row_of[:, :-s], back, constant_values=-1) == row_of
+        y = y + jnp.where(same, jnp.pad(y[:, :-s], back), 0)
+        s *= 2
+    last = a.last_ell
+    return jnp.where(last >= 0, jnp.take_along_axis(y, jnp.maximum(last, 0), axis=1), 0)
 
 
 def _rows_kernel(cols, vals, x_full, k_major: bool):
@@ -220,7 +235,7 @@ def _spmv_local(a: PartitionedELL, x_full: jax.Array, grain: int) -> jax.Array:
         (chunks(a.cols, -1), chunks(a.vals, 0)),
     )  # (n_chunks, P, g)
     y = y.transpose(1, 0, 2).reshape(P, n_chunks * g)[:, :rp]
-    return fold_pieces(y, a.row_of, ceil_div(a.shape[0], P))
+    return fold_pieces(y, a)
 
 
 def spmv_local(
@@ -248,11 +263,10 @@ def spmv_mesh(
 
     n = a.shape[1]
     k_major = _k_major(*a.cols.shape[1:])
-    rows_out = ceil_div(a.shape[0], a.P)
 
     def rows(a_p: PartitionedELL, x_full):
         c, v = (a_p.cols[0].T, a_p.vals[0].T) if k_major else (a_p.cols[0], a_p.vals[0])
-        return fold_pieces(_rows_kernel(c, v, x_full, k_major)[None], a_p.row_of, rows_out)
+        return fold_pieces(_rows_kernel(c, v, x_full, k_major)[None], a_p)
 
     if strategy.replicate_x:
         # x already local everywhere: pure local compute (paper's S1 win)

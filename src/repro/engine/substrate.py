@@ -34,7 +34,6 @@ from ..core.bfs import bfs_local, bfs_mesh
 from ..core.gsana import NEG, compute_similarity, compute_similarity_mesh
 from ..core.spmv import fold_pieces, spmv_local, spmv_mesh, unstripe_vector
 from ..core.strategies import MigratoryStrategy, Scheme
-from ..core.util import ceil_div
 from .api import OpNotSupportedError
 from .registry import default_registry, kernel
 
@@ -366,7 +365,7 @@ def _spmv_pallas(sub: PallasSubstrate, a, x, *, strategy):
         a.cols.reshape(p * rp, k), a.vals.reshape(p * rp, k), x_full,
         grain=max(1, min(grain, p * rp)), interpret=sub.interpret,
     )
-    return fold_pieces(y.reshape(p, rp), a.row_of, ceil_div(a.shape[0], p))
+    return fold_pieces(y.reshape(p, rp), a)
 
 
 @kernel("bfs", "pallas")

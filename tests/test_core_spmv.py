@@ -248,6 +248,59 @@ def test_pallas_substrate_folds_the_pieces():
     _assert_matches_ref(d, x, _served(pe, x, MigratoryStrategy(grain=64), "pallas"))
 
 
+def _layout_of_lengths(lens, p: int, k: int):
+    rng = np.random.default_rng(len(lens))
+    d = np.zeros((len(lens), max(lens)), np.float32)
+    for r, deg in enumerate(lens):
+        d[r, rng.choice(d.shape[1], deg, replace=False)] = 1.0
+    return partition_ell(CSR.from_dense(d), p, k=k)
+
+
+FOLD_LAYOUTS = {
+    # rows with no nonzero hold no ELL row in a split layout
+    "empty_rows": ([0, 5, 0, 0, 7, 1, 0, 3, 0, 9, 0, 0, 2, 0, 0, 4], 4, 2),
+    # row 0 in 16 = 2^4 pieces, then in 17: the scan's last step must reach back
+    "pow2_pieces": ([32, 1, 1, 1, 3, 0, 2, 1], 4, 2),
+    "pow2_plus_1_pieces": ([34, 1, 1, 1, 3, 0, 2, 1], 4, 2),
+    # row 0's 64 pieces are every ELL row of nodelet 0 (row 4 is empty)
+    "hub_fills_nodelet": ([128, 3, 2, 1, 0, 2, 1, 1], 4, 2),
+    # 21 rows over 4 nodelets: slot 5 of nodelets 1 to 3 lies past n
+    "padding_past_n": ([3, 0, 8, 1, 2, 2, 0, 5, 1, 1, 4, 0, 6, 1, 2, 3, 0, 1, 2, 30, 1], 4, 3),
+}
+
+
+@pytest.mark.parametrize("layout", list(FOLD_LAYOUTS))
+def test_fold_pieces_matches_float64_add_at(layout):
+    """The segmented scan and gather of ``fold_pieces`` against a float64
+    ``np.add.at`` of every ELL row's sum onto the slot ``row_of`` names:
+    within 1e-6 of the sum of the magnitudes added, and exactly 0 on a slot
+    that has no ELL row (an empty row, or a slot past n)."""
+    import jax
+
+    from repro.core.spmv import fold_pieces
+
+    lens, p, k = FOLD_LAYOUTS[layout]
+    pe = _layout_of_lengths(lens, p, k)
+    assert pe.row_of is not None
+    row_of = np.asarray(pe.row_of)
+    rp = -(-len(lens) // p)
+    if layout == "hub_fills_nodelet":
+        assert (row_of[0] == 0).all()
+    y = np.random.default_rng(7).standard_normal(row_of.shape).astype(np.float32)
+    ref, mag = np.zeros((p, rp + 1)), np.zeros((p, rp + 1))
+    for q in range(p):
+        np.add.at(ref[q], row_of[q], y[q].astype(np.float64))
+        np.add.at(mag[q], row_of[q], np.abs(y[q]).astype(np.float64))
+    out = np.asarray(jax.jit(fold_pieces)(jnp.asarray(y), pe))
+    assert out.shape == (p, rp)
+    assert np.all(np.abs(out - ref[:, :rp]) <= 1e-6 * mag[:, :rp])
+    held = np.zeros(p * rp, bool)
+    held[: len(lens)] = np.asarray(lens) > 0
+    held = held.reshape(rp, p).T
+    assert np.array_equal(np.asarray(pe.last_ell) >= 0, held)
+    assert np.all(out[~held] == 0)
+
+
 def test_plan_key_covers_the_split():
     from repro.engine import SpMVInputs, SpMVOp, build_plan
 

@@ -86,13 +86,14 @@ def _compile(fn, *args):
 
 def _compile_local_spmv(shapes, replicate_x, n=LAP_ROWS, k=LAP_K, ell_rows=None, grain=None):
     """``ell_rows``: R_p' of a layout whose long rows are split, with its
-    row map; None for one ELL row per row. ``grain`` None is the dynamic
-    grain."""
+    row map and each row's last ELL row; None for one ELL row per row.
+    ``grain`` None is the dynamic grain."""
     rp = -(-n // P)
     rows = ell_rows or rp
     a = PartitionedELL(
         cols=shapes((P, rows, k), jnp.int32), vals=shapes((P, rows, k)), shape=(n, n),
         row_of=shapes((P, rows), jnp.int32) if ell_rows else None,
+        last_ell=shapes((P, rp), jnp.int32) if ell_rows else None,
     )
     x = shapes((n,)) if replicate_x else shapes((P, rp))
     st = MigratoryStrategy(replicate_x=replicate_x, grain=grain)
@@ -173,16 +174,18 @@ STANFORD_ROWS, STANFORD_ELL_ROWS, STANFORD_K, STANFORD_GRAIN = 281_903, 139_107,
 @pytest.mark.parametrize("split", [True, False], ids=["stanford_split", "laplacian"])
 def test_local_spmv_folds_pieces_only_where_rows_are_split(shapes, split):
     """The split layout compiles for a v5e with one gather in the row-chunk
-    loop and one scatter-add folding the pieces onto their rows, in bounded
-    temp; a layout without a split (the Laplacian) compiles no fold."""
+    loop and a fold of the pieces onto their rows that is a segmented scan
+    and one gather of each row's last ELL row, with no scatter, in bounded
+    temp; a layout without a split (the Laplacian) compiles no fold: one
+    gather, no scatter."""
     if split:
         compiled = _compile_local_spmv(shapes, True, n=STANFORD_ROWS, k=STANFORD_K,
                                        ell_rows=STANFORD_ELL_ROWS, grain=STANFORD_GRAIN)
     else:
         compiled = _compile_local_spmv(shapes, True)
     hlo = compiled.as_text()
-    assert len(re.findall(r" gather\(", hlo)) == 1
-    assert len(re.findall(r" scatter\(", hlo)) == int(split)
+    assert len(re.findall(r" gather\(", hlo)) == 1 + int(split)
+    assert not re.findall(r" scatter\(", hlo)
     assert compiled.memory_analysis().temp_size_in_bytes < 128 * 10**6
 
 

@@ -202,7 +202,8 @@ def test_request_roundtrip_and_execution_parity(idx):
 
 
 def test_split_spmv_matrix_roundtrips_and_runs():
-    """A layout with hub rows split keeps its row map across the wire."""
+    """A layout with hub rows split keeps its row map and each row's last
+    ELL row across the wire."""
     from repro.sparse import skewed_matrix
 
     a = partition_ell(skewed_matrix(256, 6.0, 120, seed=3), 4)
@@ -211,6 +212,7 @@ def test_split_spmv_matrix_roundtrips_and_runs():
     request = Request("spmv", SpMVInputs(a, x), MigratoryStrategy(), "local")
     rebuilt = Request.from_wire(json.loads(json.dumps(request.to_wire())))
     np.testing.assert_array_equal(np.asarray(rebuilt.inputs.a.row_of), np.asarray(a.row_of))
+    np.testing.assert_array_equal(np.asarray(rebuilt.inputs.a.last_ell), np.asarray(a.last_ell))
     assert rebuilt.inputs.a.shape == a.shape
     y0, _ = run(request, iters=1, warmup=0)
     y1, _ = run(rebuilt, iters=1, warmup=0)
