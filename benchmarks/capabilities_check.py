@@ -27,11 +27,11 @@ from repro.engine import (
 )
 
 
-# The pinned support matrix: PR 7 made pallas a real fast path for bfs
-# (kernels/bfs); moe_dispatch stays local/mesh-only by design.
+# The pinned support matrix: pallas serves GSANA only (kernels/topk_sim);
+# moe_dispatch stays local/mesh-only by design.
 EXPECTED_CAPABILITIES = {
-    "spmv": {"local": True, "mesh": True, "pallas": True},
-    "bfs": {"local": True, "mesh": True, "pallas": True},
+    "spmv": {"local": True, "mesh": True, "pallas": False},
+    "bfs": {"local": True, "mesh": True, "pallas": False},
     "gsana": {"local": True, "mesh": True, "pallas": True},
     "moe_dispatch": {"local": True, "mesh": True, "pallas": False},
 }

@@ -1,45 +1,17 @@
 """Kernel micro-benches (interpret-mode correctness-path timings on CPU; on
 TPU these run natively — the numbers here track relative effects only):
-SpMV grain sweep through the Pallas grid, flash-attention block sizes,
-fused topk-sim vs unfused reference, and the engine-level pallas-vs-local
-A/B (the rows ``--require-pallas-speedup`` gates)."""
+flash-attention block sizes and fused topk-sim vs unfused reference."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.spmv.ops import spmv as spmv_kernel
-from repro.kernels.spmv.ref import spmv_ell_reference
-from repro.kernels.spmv.stripe import build_stripe_plan, spmv_ell_stripes
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_reference
 from repro.kernels.topk_sim.ops import topk_sim_pairs
-from repro.core import (
-    MigratoryStrategy,
-    bucketize,
-    generate_alignment_pair,
-    neighbor_buckets,
-    partition_ell,
-    pick_grid,
-)
+from repro.core import bucketize, generate_alignment_pair, neighbor_buckets, pick_grid
 
-from .util import emit, emit_report, time_fn
-
-
-def spmv_kernel_grain(full: bool = False, quick: bool = False):
-    rows = []
-    rng = np.random.default_rng(0)
-    r, k, n = (16384, 8, 16384) if full else ((1024, 8, 1024) if quick else (4096, 8, 4096))
-    cols = jnp.asarray(rng.integers(-1, n, size=(r, k)).astype(np.int32))
-    vals = jnp.asarray(np.where(np.asarray(cols) >= 0, rng.standard_normal((r, k)), 0).astype(np.float32))
-    x = jnp.asarray(rng.standard_normal(n).astype(np.float32))
-    sec_ref = time_fn(lambda: spmv_ell_reference(cols, vals, x), iters=3)
-    rows.append(emit("kernel_spmv", "jnp_ref", sec_ref))
-    for grain in (64, 256, 1024):
-        sec = time_fn(lambda: spmv_kernel(cols, vals, x, grain=grain), iters=3)
-        rows.append(emit("kernel_spmv", f"pallas_grain={grain}", sec))
-    return rows
+from .util import emit, time_fn
 
 
 def flash_blocks(full: bool = False, quick: bool = False):
@@ -78,77 +50,5 @@ def topk_sim(full: bool = False, quick: bool = False):
     return rows
 
 
-def pallas_engine(full: bool = False, quick: bool = False):
-    """Engine-level substrate A/B on one SpMV/BFS problem each: the same
-    inputs through ``local`` vs ``pallas`` (vs ``mesh`` when the device
-    count covers the partition), block-size sweep included. The
-    ``spmv_local`` / ``spmv_pallas_grain=*`` pair is what run.py's
-    ``--require-pallas-speedup`` gate reads: best pallas grain vs the
-    jitted local path. Sized so the kernel is memory-bound, not
-    dispatch-bound — per-program interpreter overhead dominates tiny
-    problems and would measure the harness, not the kernel."""
-    from repro.engine import BFSInputs, BFSOp, SpMVInputs, SpMVOp
-    from repro.engine import run as engine_run
-    from repro.sparse import (
-        edges_to_csr,
-        erdos_renyi_edges,
-        laplacian_2d,
-        partition_graph,
-        skewed_matrix,
-    )
-
-    rows = []
-    p = 8
-    n = 160 if full else 96  # n^2-row Laplacian; quick keeps 9216 rows too
-    a = laplacian_2d(n)
-    x = jnp.asarray(np.random.default_rng(3).standard_normal(n * n).astype(np.float32))
-    inputs = SpMVInputs(partition_ell(a, p), x)
-    n_rows = inputs.a.cols.shape[0] * inputs.a.cols.shape[1]
-    substrates = ["local", "pallas"] + (["mesh"] if len(jax.devices()) >= p else [])
-    for sub in substrates:
-        if sub == "pallas":
-            grains = (n_rows,) if quick else (1024, n_rows)
-            for grain in grains:
-                st = MigratoryStrategy(grain=grain)
-                _, rep = engine_run(SpMVOp(), inputs, st, "pallas", iters=5)
-                rows.append(
-                    emit_report("kernel_pallas_engine", f"spmv_pallas_grain={grain}", rep)
-                )
-        else:
-            _, rep = engine_run(SpMVOp(), inputs, MigratoryStrategy(), sub, iters=5)
-            rows.append(emit_report("kernel_pallas_engine", f"spmv_{sub}", rep))
-    scale = 8 if quick else 10
-    g = partition_graph(edges_to_csr(erdos_renyi_edges(scale, 8, seed=4), 1 << scale), p)
-    binputs = BFSInputs(g, 0)
-    for sub in substrates:
-        st = MigratoryStrategy(grain=(1 << scale) if sub == "pallas" else None)
-        _, rep = engine_run(BFSOp(), binputs, st, sub, iters=3)
-        rows.append(emit_report("kernel_pallas_engine", f"bfs_{sub}", rep))
-    # stripe-vs-dense-ELL A/B on a hub-skewed matrix (paper Table 3's
-    # pathology): stripes shed the padding the dense kernel executes
-    ns = 1024 if quick else 4096
-    sk = skewed_matrix(ns, avg_deg=4.0, max_deg=ns // 8, seed=9)
-    from repro.sparse import ell_from_csr
-
-    e = ell_from_csr(sk)
-    xs = jnp.asarray(np.random.default_rng(4).standard_normal(ns).astype(np.float32))
-    plan = build_stripe_plan(e.cols, block_rows=max(64, ns // 16))
-    sec = time_fn(lambda: spmv_kernel(e.cols, e.vals, xs, grain=ns), iters=3)
-    rows.append(emit("kernel_pallas_engine", "spmv_skewed_ell", sec,
-                     padded_slots=int(e.cols.shape[0] * e.cols.shape[1])))
-    sec = time_fn(
-        lambda: spmv_ell_stripes(e.cols, e.vals, xs,
-                                 block_rows=max(64, ns // 16), plan=plan),
-        iters=3,
-    )
-    rows.append(emit("kernel_pallas_engine", "spmv_skewed_stripes", sec,
-                     padded_slots=int(plan.padded_slots),
-                     waste_ratio=round(float(plan.waste_ratio), 3)))
-    return rows
-
-
 def run(full: bool = False, quick: bool = False):
-    return (
-        spmv_kernel_grain(full, quick) + flash_blocks(full, quick)
-        + topk_sim(full, quick) + pallas_engine(full, quick)
-    )
+    return flash_blocks(full, quick) + topk_sim(full, quick)

@@ -97,12 +97,6 @@ def main(argv=None) -> None:
         "becomes the service's declared slo_target_seconds)",
     )
     ap.add_argument(
-        "--require-pallas-speedup", type=float, default=0.0,
-        help="fail unless the kernels suite's best pallas SpMV row is at "
-        "least this multiple faster than the jitted local path (CI uses "
-        "1.0: the fast path must not be a slow path)",
-    )
-    ap.add_argument(
         "--cluster", type=int, default=None, metavar="N",
         help="run the cluster suite on an N-worker localhost cluster "
         "(multi-process serving plane; fail-closed parity + distribution "
@@ -245,47 +239,8 @@ def main(argv=None) -> None:
                 file=sys.stderr,
             )
             sys.exit(1)
-    if args.require_pallas_speedup > 0:
-        _gate_pallas_speedup(all_rows, args.require_pallas_speedup)
     if args.require_model_band > 0:
         _gate_model_band(all_rows, args.require_model_band)
-
-
-def _gate_pallas_speedup(all_rows: list, min_speedup: float) -> None:
-    """The kernels suite's engine A/B must show the pallas fast path is
-    one: best ``spmv_pallas_grain=*`` seconds vs the ``spmv_local`` row.
-    Fails closed — a gate with no rows to read (suite skipped or renamed)
-    must not pass green."""
-    local = [
-        r for r in all_rows
-        if r.get("bench") == "kernel_pallas_engine" and r.get("case") == "spmv_local"
-    ]
-    pallas = [
-        r for r in all_rows
-        if r.get("bench") == "kernel_pallas_engine"
-        and str(r.get("case", "")).startswith("spmv_pallas_grain=")
-    ]
-    if not local or not pallas:
-        print(
-            "# FAIL: --require-pallas-speedup found no kernel_pallas_engine "
-            "spmv rows (did the kernels suite run?)",
-            file=sys.stderr,
-        )
-        sys.exit(1)
-    best = min(pallas, key=lambda r: float(r["seconds"]))
-    speedup = float(local[0]["seconds"]) / float(best["seconds"])
-    print(
-        f"# pallas speedup: local {float(local[0]['seconds'])*1e6:.1f}us / "
-        f"best pallas ({best['case']}) {float(best['seconds'])*1e6:.1f}us "
-        f"= {speedup:.2f}x (need >= {min_speedup:g})"
-    )
-    if speedup < min_speedup:
-        print(
-            f"# FAIL: pallas SpMV fast path is {speedup:.2f}x the jitted "
-            f"local path, below the {min_speedup:g}x floor",
-            file=sys.stderr,
-        )
-        sys.exit(1)
 
 
 def _gate_model_band(all_rows: list, band: float) -> None:

@@ -21,8 +21,8 @@ runs, at sizes a graph or sparse user would call real:
   ``(P, V_p, K)`` adjacency holds on one chip;
 - ``gsana``: n=8192 PAIR alignment, bit-identical to the jitted core
   function, recall@4 against the planted ground truth;
-- ``pallas``: each Pallas kernel on its engine op through ``"pallas"`` at
-  the same sizes; kernels the TPU compiler refuses must refuse at plan time;
+- ``pallas``: GSANA PAIR through ``"pallas"`` (the ``topk_sim`` kernel) on
+  the same inputs, against the ``local`` result;
 - ``moe_decode``: ``DecodeServer`` continuous batching of ``serve-moe``
   against ``moe_decode_reference``.
 
@@ -78,7 +78,6 @@ from repro.engine import (  # noqa: E402
     GSANAInputs,
     GSANAOp,
     MoEDispatchInputs,
-    OpNotSupportedError,
     Request,
     SpMVInputs,
     SpMVOp,
@@ -217,7 +216,7 @@ def pick_roots(csr: sp.csr_matrix, count: int, rng: np.random.Generator) -> list
 # -- one-chip phases ---------------------------------------------------------
 
 
-def phase_spmv(service, sizes, rng, ctx, p: int = 8) -> None:
+def phase_spmv(service, sizes, rng, p: int = 8) -> None:
     start = clock(service)
     a = laplacian_2d(sizes.lap_n)
     ref_matrix = host_csr(a)
@@ -230,7 +229,6 @@ def phase_spmv(service, sizes, rng, ctx, p: int = 8) -> None:
     requests = [Request(SpMVOp(), inputs, st) for st in strategies for _ in range(2)]
     responses = serve(service, requests)
     errs = [rel_err(np.asarray(gather_result(r.result, n)), ref) for r in responses]
-    ctx["spmv"] = (inputs, np.asarray(gather_result(responses[0].result, n)), ref)
     emit(
         service, "spmv", start, max(errs) <= SPMV_TOL,
         rows=n, nnz=int(ref_matrix.nnz), nodelets=p,
@@ -249,7 +247,7 @@ def _bfs_graph(edges: np.ndarray, scale: int, p: int):
     return graph, host
 
 
-def phase_bfs(service, name, edges, scale, sizes, rng, ctx, p: int = 8) -> None:
+def phase_bfs(service, name, edges, scale, sizes, rng, p: int = 8) -> None:
     start = clock(service)
     graph, host = _bfs_graph(edges, scale, p)
     del edges
@@ -269,7 +267,6 @@ def phase_bfs(service, name, edges, scale, sizes, rng, ctx, p: int = 8) -> None:
         ref_reached = len(breadth_first_order(host, root, return_predecessors=False))
         reached.append(int((parents >= 0).sum()))
         reached_match &= reached[-1] == ref_reached
-    ctx[name] = (BFSInputs(graph, roots[0]), np.asarray(responses[0].result))
     emit(
         service, name, start, bool(trees_valid and reached_match),
         scale=scale, vertices=graph.n_vertices, directed_edges=int(host.nnz),
@@ -291,7 +288,7 @@ def _gsana_inputs(n: int, seed: int) -> GSANAInputs:
     )
 
 
-def phase_gsana(service, sizes, seed, ctx) -> None:
+def phase_gsana(service, sizes, seed) -> tuple:
     start = clock(service)
     inputs = _gsana_inputs(sizes.gsana_n, seed)
     st = MigratoryStrategy(scheme=Scheme.PAIR)
@@ -308,7 +305,6 @@ def phase_gsana(service, sizes, seed, ctx) -> None:
         for r in responses
     )
     recall = recall_at_k(responses[0].result[0], inputs.ground_truth)
-    ctx["gsana"] = (inputs, cand_o, score_o)
     emit(
         service, "gsana", start, bool(identical and recall > RECALL_FLOOR),
         n=sizes.gsana_n, scheme="pair", k=k, buckets=inputs.b2.grid ** 2,
@@ -318,56 +314,28 @@ def phase_gsana(service, sizes, seed, ctx) -> None:
         bit_identical_to_oracle=bool(identical), recall_at_4=recall,
         recall_floor=RECALL_FLOOR,
     )
+    return inputs, cand_o, score_o
 
 
-def phase_pallas(service, ctx) -> None:
-    """Each Pallas kernel on its engine op at the sizes above. A kernel the
-    TPU compiler refuses must refuse at plan time, never interpret."""
+def phase_pallas(service, gsana_inputs, cand_l, score_l) -> None:
+    """GSANA PAIR through the ``topk_sim`` Pallas kernel on the GSANA
+    phase's inputs, against that phase's result."""
     start = clock(service)
     sub = PallasSubstrate()
-    spmv_inputs, spmv_local, _ = ctx["spmv"]
-    bfs_inputs, bfs_local = ctx["bfs_urand"]
-    gsana_inputs, cand_l, score_l = ctx["gsana"]
-    cases = {
-        "spmv": Request(SpMVOp(), spmv_inputs, MigratoryStrategy(), "pallas"),
-        "bfs": Request(BFSOp(), bfs_inputs, EP_PULL, "pallas"),
-        "gsana": Request(GSANAOp(), gsana_inputs, MigratoryStrategy(scheme=Scheme.PAIR), "pallas"),
-    }
-    outcome, matched, responses = {}, True, []
-    for op, request in cases.items():
-        reason = sub.refusal(op)
-        future = service.submit(request)
-        if reason is not None:
-            try:
-                future.result()
-            except OpNotSupportedError as exc:
-                outcome[op] = f"refused at plan time: {exc}"
-                continue
-            outcome[op] = "ran although the TPU compiler refuses it"
-            matched = False
-            continue
-        response = future.result()
-        responses.append(response)
-        if op == "spmv":
-            y = np.asarray(gather_result(response.result, spmv_inputs.a.shape[0]))
-            ok = rel_err(y, spmv_local.astype(np.float64)) <= SPMV_TOL
-        elif op == "bfs":
-            ok = np.array_equal(np.asarray(response.result), bfs_local)
-        else:
-            cand_p, score_p = (np.asarray(a) for a in response.result)
-            fin = np.isfinite(score_l)
-            ok = bool(
-                np.array_equal(fin, np.isfinite(score_p))
-                and np.allclose(score_l[fin], score_p[fin], atol=PALLAS_SCORE_ATOL, rtol=0)
-                and recall_at_k(jnp.asarray(cand_p), gsana_inputs.ground_truth) > RECALL_FLOOR
-            )
-            outcome["gsana_candidates_equal_local"] = float(np.mean(cand_p == cand_l))
-        outcome[op] = "compiled, matches local" if ok else "compiled, DOES NOT match local"
-        matched &= ok
+    request = Request(GSANAOp(), gsana_inputs, MigratoryStrategy(scheme=Scheme.PAIR), "pallas")
+    response = serve(service, [request])[0]
+    cand_p, score_p = (np.asarray(a) for a in response.result)
+    fin = np.isfinite(score_l)
+    matched = bool(
+        np.array_equal(fin, np.isfinite(score_p))
+        and np.allclose(score_l[fin], score_p[fin], atol=PALLAS_SCORE_ATOL, rtol=0)
+        and recall_at_k(jnp.asarray(cand_p), gsana_inputs.ground_truth) > RECALL_FLOOR
+    )
     emit(
         service, "pallas", start, matched,
-        interpret=sub.interpret, requests=len(cases), outcome=outcome,
-        first_call_seconds=first_call_seconds(responses),
+        interpret=sub.interpret, requests=1,
+        first_call_seconds=first_call_seconds([response]),
+        candidates_equal_local=float(np.mean(cand_p == cand_l)),
         score_atol=PALLAS_SCORE_ATOL,
     )
 
@@ -536,20 +504,16 @@ def main(argv=None) -> int:
             phase_mesh_bfs(service, sizes, rng, args.seed)
             phase_mesh_moe(service, sizes, args.seed)
         else:
-            ctx: dict = {}
-            phase_spmv(service, sizes, rng, ctx)
+            phase_spmv(service, sizes, rng)
             gc.collect()
             urand = erdos_renyi_edges(sizes.urand_scale, 16, seed=args.seed)
-            phase_bfs(service, "bfs_urand", urand, sizes.urand_scale, sizes, rng, ctx)
+            phase_bfs(service, "bfs_urand", urand, sizes.urand_scale, sizes, rng)
             del urand
-            phase_gsana(service, sizes, args.seed, ctx)
-            phase_pallas(service, ctx)
-            ctx.clear()
+            phase_pallas(service, *phase_gsana(service, sizes, args.seed))
             gc.collect()
             kron = rmat_edges(sizes.kron_scale, 16, seed=args.seed)
-            phase_bfs(service, "bfs_kron", kron, sizes.kron_scale, sizes, rng, ctx)
+            phase_bfs(service, "bfs_kron", kron, sizes.kron_scale, sizes, rng)
             del kron
-            ctx.clear()
             gc.collect()
             phase_moe_decode(service, args.seed)
     finally:

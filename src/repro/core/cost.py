@@ -54,15 +54,6 @@ class CostEstimate:
     to the traffic units) otherwise. ``detail["collective_launches"]``
     counts how many collective dispatches the strategy issues (BFS pays one
     per round), feeding the alpha term.
-
-    ``detail["substrate_memory"]`` maps a substrate kind to that backend's
-    *own* per-launch working set + access class when its kernel executes a
-    different memory shape than the generic path — the Pallas kernels
-    replicate x into every grid program's VMEM (SpMV) and min-merge a
-    dense partial per program (BFS), so their sweeps depend on the grain
-    axis (``block_rows``). The perf model prefers the targeted declaration
-    over the generic one, which is what makes predicted seconds rank block
-    sizes.
     """
 
     strategy: MigratoryStrategy
@@ -94,7 +85,6 @@ def spmv_cost_model(inputs) -> CostModel:
     p_idx = np.arange(p)[:, None, None]
     remote_nnz = int(((cols >= 0) & ((cols % p) != p_idx)).sum())
     rp = a.rows_per_nodelet
-    n_cols = a.shape[1]
     # what one launch streams: the *padded* ELL slab (vals f32 + cols i32,
     # padding included — skewed matrices execute their padding) plus x
     # gathered and y written, per ELL row: where hub rows are split, R_p'
@@ -108,12 +98,6 @@ def spmv_cost_model(inputs) -> CostModel:
         tasks = ceil_div(rp, max(1, min(grain, rp))) * p
         target = min(GRAIN_TARGET_TASKS, rp) * p
         balance = abs(tasks - target) / max(target, 1)
-        # the pallas kernel's launch shape (mirrors _spmv_pallas: planes
-        # flattened to p*rp rows, block_rows = grain): every grid program
-        # replicates x (S1 in VMEM), so small blocks multiply the x sweep
-        block = max(1, min(grain, p * rp))
-        programs = ceil_div(p * rp, block)
-        pallas_bytes = sweep_bytes + programs * n_cols * 4
         return CostEstimate(
             strategy=st,
             traffic_bytes=migrations * CONTEXT_BYTES,
@@ -123,13 +107,6 @@ def spmv_cost_model(inputs) -> CostModel:
                 "collective_launches": 1,
                 "memory_bytes_per_launch": sweep_bytes,
                 "memory_access": "gather",
-                "substrate_memory": {
-                    "pallas": {
-                        "bytes_per_launch": pallas_bytes,
-                        "access": "gather",
-                        "programs": programs,
-                    },
-                },
             },
             traffic=TrafficStats(migrations=migrations),
         )
@@ -151,20 +128,12 @@ def bfs_cost_model(inputs) -> CostModel:
     # serialized read-modify-write path, not the triad), times rounds
     p, vp, k = inputs.g.adj.shape
     sweep_bytes = 12 * p * vp * k
-    n_pad = p * vp
 
     def estimate(st: MigratoryStrategy) -> CostEstimate:
         if st.comm == Comm.MIGRATE:
             split = TrafficStats(migrations=2 * remote_edges)
         else:
             split = TrafficStats(remote_writes=remote_edges)
-        # the pallas round kernel's launch shape (mirrors bfs_pallas:
-        # block_rows = grain over the global adjacency): each grid program
-        # builds and min-merges a dense (N_pad,) partial, so small blocks
-        # multiply the accumulator sweep — the per-block-aggregation cost
-        block = max(1, min(st.dynamic_grain(n_pad), n_pad))
-        programs = ceil_div(n_pad, block)
-        pallas_bytes = sweep_bytes + programs * n_pad * 8
         return CostEstimate(
             strategy=st,
             traffic_bytes=split.total_bytes,
@@ -179,13 +148,6 @@ def bfs_cost_model(inputs) -> CostModel:
                 "collective_launches": stats.rounds,
                 "memory_bytes_per_launch": sweep_bytes,
                 "memory_access": "scatter",
-                "substrate_memory": {
-                    "pallas": {
-                        "bytes_per_launch": pallas_bytes,
-                        "access": "scatter",
-                        "programs": programs,
-                    },
-                },
             },
             traffic=split,
         )

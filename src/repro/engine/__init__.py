@@ -49,7 +49,6 @@ from .probes import ProbeStore, default_probe_store
 from .ops import (
     OPS,
     GRAIN_CANDIDATES,
-    PALLAS_BLOCK_CANDIDATES,
     BFSInputs,
     BFSOp,
     GSANAInputs,
@@ -132,7 +131,7 @@ __all__ = [
     "GSANAOp", "KernelRegistry", "LocalSubstrate", "MeshSubstrate",
     "MigratoryOp", "MoEDecodeInputs", "MoEDecodeOp",
     "MoEDispatchInputs", "MoEDispatchOp", "OPS", "OpSpec",
-    "OpNotSupportedError", "PALLAS_BLOCK_CANDIDATES", "PallasSubstrate",
+    "OpNotSupportedError", "PallasSubstrate",
     "PlanCache", "ProbeStore",
     "RankedCandidate", "Request",
     "RunReport", "SegmentTable", "ServiceFuture", "ServiceRequest",

@@ -32,14 +32,13 @@ of re-probing.
 from __future__ import annotations
 
 import dataclasses
-import inspect
 from typing import Any
 
 from ..core.cost import CostEstimate, cost_model_for
 from ..core.strategies import MigratoryStrategy, strategy_grid
 from ..machine.machine import MachineProfile, default_machine
 from ..machine.perfmodel import PerformanceModel
-from .api import ExecutionPlan, OpNotSupportedError, RunReport, strategy_dict
+from .api import ExecutionPlan, RunReport, strategy_dict
 from .cache import PlanCache
 from .ops import GRAIN_CANDIDATES  # noqa: F401  (legacy re-export; lives with the OpSpecs)
 from .probes import ProbeStore
@@ -48,33 +47,14 @@ from .runner import build_plan, resolve_op, run
 from .substrate import Substrate
 
 
-def candidate_grid(
-    op_name: str, substrate: "Substrate | str | None" = None
-) -> list[MigratoryStrategy]:
+def candidate_grid(op_name: str) -> list[MigratoryStrategy]:
     """The autotuner's search space for one op: the op's registered
     ``OpSpec.grid`` (e.g. SpMV populates the grain axis, ``moe_dispatch``
-    varies only S2), else the default S1 x S2 x S3 cross product.
-
-    ``substrate`` targets the grid at a backend: grid callables that accept
-    an argument receive the substrate *kind* and may widen a kernel-tuning
-    axis for it (SpMV/BFS enumerate Pallas ``block_rows``); zero-arg grids
-    are called as before, so the substrate-blind contract is unchanged. A
-    backend that refuses the op here (:meth:`Substrate.refusal`) has no
-    grid: :class:`OpNotSupportedError`."""
+    varies only S2), else the default S1 x S2 x S3 cross product. The grid
+    does not depend on the substrate."""
     spec = default_registry().op_spec(op_name)
     if spec.grid is None:
         return strategy_grid()
-    kind = None
-    if substrate is not None:
-        from .substrate import get_substrate
-
-        sub = get_substrate(substrate)
-        reason = sub.refusal(op_name)
-        if reason is not None:
-            raise OpNotSupportedError(reason)
-        kind = sub.substrate_kind
-    if inspect.signature(spec.grid).parameters:
-        return spec.grid(kind)
     return spec.grid()
 
 
@@ -152,7 +132,7 @@ def rank_strategies(
     untouched and the ordering is bit-identical to the traffic units."""
     op = resolve_op(op)
     model = cost_model_for(op.name, inputs)
-    cands = candidates if candidates is not None else candidate_grid(op.name, substrate)
+    cands = candidates if candidates is not None else candidate_grid(op.name)
     estimates = [model(st) for st in cands]
     profile = machine if machine is not None else default_machine()
     if profile.calibrated:
@@ -228,23 +208,14 @@ def autotune(
     best = candidates[0].estimate.strategy
     if probe_top_k > 0:
         # probe only cost-distinct candidates: grid points whose estimates tie
-        # exactly differ in axes the op never reads, so one probe covers them.
-        # The substrate-targeted working set (and predicted seconds, when
-        # calibrated) join the signature so block-size variants that tie in
-        # traffic units — the whole Pallas grain axis does — still get their
-        # own probes: the target substrate's kernel *does* read that axis.
-        from .substrate import get_substrate
-
-        kind = get_substrate(substrate).substrate_kind
+        # exactly differ in axes the op never reads, so one probe covers them
         probed: list[RankedCandidate] = []
         seen_costs: set[tuple] = set()
         for cand in candidates:
-            targeted = (cand.estimate.detail.get("substrate_memory") or {}).get(kind)
             cost_sig = (
                 cand.estimate.traffic_bytes,
                 cand.estimate.balance_penalty,
                 cand.estimate.predicted_seconds,
-                targeted.get("bytes_per_launch") if targeted else None,
             )
             if cost_sig in seen_costs:
                 continue

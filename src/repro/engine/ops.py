@@ -51,24 +51,9 @@ from .substrate import Substrate
 # SpMV's autotune grid sweeps them, the other ops' grids pin grain=None
 GRAIN_CANDIDATES = (None, 16, 64, 256)
 
-# the Pallas kernel-tuning axis: grain = block_rows (rows per grid program).
-# Wider and coarser than the generic sweep — VMEM-tile-shaped candidates;
-# tiny grains are never competitive once the per-program x/partial
-# replication is charged (core/cost.py substrate_memory)
-PALLAS_BLOCK_CANDIDATES = (None, 64, 128, 256, 512, 1024)
 
-
-def _spmv_grid(substrate_kind: "str | None" = None) -> list[MigratoryStrategy]:
-    grains = PALLAS_BLOCK_CANDIDATES if substrate_kind == "pallas" else GRAIN_CANDIDATES
-    return strategy_grid(grains=grains)
-
-
-def _bfs_grid(substrate_kind: "str | None" = None) -> list[MigratoryStrategy]:
-    # default grid pins grain=None (the local/mesh kernels never read it);
-    # on pallas the grain is block_rows of the frontier-expansion kernel
-    if substrate_kind == "pallas":
-        return strategy_grid(grains=PALLAS_BLOCK_CANDIDATES)
-    return strategy_grid()
+def _spmv_grid() -> list[MigratoryStrategy]:
+    return strategy_grid(grains=GRAIN_CANDIDATES)
 
 
 # Cross-plan memo for host-side derived stats (traffic replays, placement
@@ -317,7 +302,6 @@ register_op(OpSpec(
     factory=BFSOp,
     inputs_type=BFSInputs,
     cost_model=bfs_cost_model,
-    grid=_bfs_grid,
 ))
 register_op(OpSpec(
     name="gsana",

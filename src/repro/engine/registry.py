@@ -52,20 +52,15 @@ class OpSpec:
     autotuner's analytic factory ``inputs -> (strategy -> CostEstimate)``;
     registering a spec installs it into ``core.cost`` so
     ``cost_model_for(name, inputs)`` serves every registered op from one
-    lookup. ``grid`` yields the op's autotune candidate strategies (None:
-    the default S1 x S2 x S3 cross product); a grid callable that accepts
-    an argument is called with the target *substrate kind* (or None), so
-    an op can widen a kernel-tuning axis per backend — SpMV/BFS enumerate
-    Pallas ``block_rows`` candidates only when tuning for ``"pallas"``,
-    while zero-arg grids stay substrate-blind
-    (:func:`~repro.engine.autotune.candidate_grid` adapts the call).
+    lookup. ``grid`` is a zero-argument callable yielding the op's autotune
+    candidate strategies (None: the default S1 x S2 x S3 cross product).
     """
 
     name: str
     factory: Callable[[], Any]
     inputs_type: "type | None" = None
     cost_model: "Callable[[Any], Any] | None" = None
-    grid: "Callable[..., list] | None" = None
+    grid: "Callable[[], list] | None" = None
 
 
 class KernelRegistry:

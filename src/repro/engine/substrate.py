@@ -6,9 +6,8 @@ Three built-in backends, mirroring the realizations the paper compares:
   (the correctness oracle; what the Emu sees as one node).
 - ``mesh``   — ``shard_map`` over a 1-D nodelet axis (the Chick's nodelets
   as TPU shards): replication, all_gather pulls, all_to_all pushes.
-- ``pallas`` — routes the compute hot loops to the Pallas kernels
-  (``kernels/spmv``, ``kernels/bfs``, ``kernels/topk_sim``) where shapes
-  allow.
+- ``pallas`` — routes GSANA's PAIR similarity to the Pallas
+  ``kernels/topk_sim`` kernel; it registers no SpMV or BFS kernel.
 
 A substrate no longer implements one method per op. Its per-op entry points
 are *kernels* registered against its ``substrate_kind`` in the
@@ -32,7 +31,7 @@ import jax
 
 from ..core.bfs import bfs_local, bfs_mesh
 from ..core.gsana import NEG, compute_similarity, compute_similarity_mesh
-from ..core.spmv import fold_pieces, spmv_local, spmv_mesh, unstripe_vector
+from ..core.spmv import spmv_local, spmv_mesh
 from ..core.strategies import MigratoryStrategy, Scheme
 from .api import OpNotSupportedError
 from .registry import default_registry, kernel
@@ -102,22 +101,11 @@ class Substrate:
                 return own_name
         return self.name
 
-    def refusal(self, op_name: str) -> "str | None":
-        """Why this backend cannot run ``op_name`` here although a kernel
-        is registered (e.g. the chip's compiler refuses it), else None."""
-        del op_name
-        return None
-
     def kernel(self, op_name: str) -> Callable:
         """Resolve this backend's kernel for ``op_name`` (bound to self),
         traced under ``jax.named_scope("<op>.<substrate>")`` so its XLA ops
         carry that scope. Raises :class:`OpNotSupportedError` when no kernel
-        is registered — capability *is* registry presence — or when
-        :meth:`refusal` names a reason the registered kernel cannot run on
-        this backend."""
-        reason = self.refusal(op_name)
-        if reason is not None:
-            raise OpNotSupportedError(reason)
+        is registered — capability *is* registry presence."""
         fn = default_registry().resolve_kernel(op_name, self.substrate_kind)
         scope = f"{op_name}.{self.name}"
 
@@ -129,10 +117,7 @@ class Substrate:
         return scoped
 
     def supports(self, op_name: str) -> bool:
-        return (
-            default_registry().has_kernel(op_name, self.substrate_kind)
-            and self.refusal(op_name) is None
-        )
+        return default_registry().has_kernel(op_name, self.substrate_kind)
 
     def cache_fingerprint(self) -> tuple:
         """Hashable identity for the compiled-plan cache: two substrate
@@ -262,12 +247,12 @@ class MeshSubstrate(Substrate):
 
 
 class PallasSubstrate(Substrate):
-    """Routes hot loops to the Pallas kernels (``kernels/spmv``,
-    ``kernels/bfs``, ``kernels/topk_sim``). ``interpret=None`` (default)
-    resolves from the backend — native lowering on TPU/GPU, interpret mode
-    elsewhere (:mod:`repro.kernels.runtime`); an explicit bool pins it.
-    The resolved value is part of the cache fingerprint, so plans compiled
-    under one mode never serve the other."""
+    """Routes GSANA's PAIR similarity to the Pallas ``kernels/topk_sim``
+    kernel. ``interpret=None`` (default) resolves from the backend — native
+    lowering on TPU/GPU, interpret mode elsewhere
+    (:mod:`repro.kernels.runtime`); an explicit bool pins it. The resolved
+    value is part of the cache fingerprint, so plans compiled under one mode
+    never serve the other."""
 
     name = "pallas"
 
@@ -279,27 +264,8 @@ class PallasSubstrate(Substrate):
     def cache_fingerprint(self) -> tuple:
         return (self.name, self.interpret)
 
-    def refusal(self, op_name: str) -> "str | None":
-        """Kernels the TPU compiler refuses as written refuse here at plan
-        time, on a TPU, instead of falling back to interpret mode."""
-        why = TPU_LOWERING_REFUSALS.get(op_name)
-        if why is None or self.interpret or jax.default_backend() != "tpu":
-            return None
-        return f"pallas {op_name!r} kernel has no TPU lowering: {why}"
-
     def placement_slots(self) -> int:
         return _one_device_slots()
-
-
-#: Pallas kernels that Mosaic refuses to lower for TPU as written, with the
-#: compiler's message (tests/test_tpu_compile.py pins both the refusals and
-#: the kernels that do compile). Rewriting them is kernel work of its own.
-TPU_LOWERING_REFUSALS = {
-    "spmv": "kernels/spmv gathers x[cols] with a 1-D jnp.take "
-    "('Only 2D gather is supported')",
-    "bfs": "kernels/bfs scatter-mins proposals with .at[].min "
-    "('Unimplemented primitive in Pallas TPU lowering: scatter-min')",
-}
 
 
 # -- built-in kernels ----------------------------------------------------------
@@ -350,32 +316,6 @@ def _gsana_mesh(sub: MeshSubstrate, vs1, vs2, b1, b2, k, *, strategy):
         mesh = make_nodelet_mesh(n_dev)
     return compute_similarity_mesh(
         vs1, vs2, b1, b2, k, strategy.scheme, mesh=mesh, axis_name=sub.axis_name,
-    )
-
-
-@kernel("spmv", "pallas")
-def _spmv_pallas(sub: PallasSubstrate, a, x, *, strategy):
-    from ..kernels.spmv.ops import spmv as spmv_kernel
-
-    x_full = x if strategy.replicate_x else unstripe_vector(x, a.shape[1])
-    p, rp, k = a.cols.shape
-    grain = strategy.dynamic_grain(rp)
-    # nodelet planes -> one (P*R_p', K) row block; kernel grid = row chunks
-    y = spmv_kernel(
-        a.cols.reshape(p * rp, k), a.vals.reshape(p * rp, k), x_full,
-        grain=max(1, min(grain, p * rp)), interpret=sub.interpret,
-    )
-    return fold_pieces(y.reshape(p, rp), a)
-
-
-@kernel("bfs", "pallas")
-def _bfs_pallas(sub: PallasSubstrate, g, root, *, strategy, max_rounds=None):
-    from ..kernels.bfs.ops import bfs_pallas
-
-    # both S2 strategies share the kernel (deterministic min-merge, same
-    # tree as the local oracle); the strategy contributes the grain axis
-    return bfs_pallas(
-        g, root, strategy, max_rounds, interpret=sub.interpret
     )
 
 

@@ -6,8 +6,8 @@ on accelerators that can lower Mosaic/Triton (TPU, GPU), interpret on
 everything else (CPU CI, the common case for this repo's tests). An
 explicit bool always wins — tests pin ``interpret=True`` for determinism,
 TPU runs may force ``interpret=False`` to fail loudly if lowering breaks.
-No wrapper defaults to ``True``: on a TPU a kernel either lowers or its
-caller refuses it (``PallasSubstrate.refusal``), it never interprets.
+No wrapper defaults to ``True``: on a TPU a kernel lowers, it never
+interprets.
 
 The resolved value is part of the engine's compiled-plan cache key
 (``PallasSubstrate.cache_fingerprint``); ``jax.default_backend()`` is fixed

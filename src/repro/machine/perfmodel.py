@@ -79,17 +79,9 @@ class PerformanceModel:
         # supersedes the generic useful-bytes count when present — it knows
         # the execution shape *and* the access class (stream / gather /
         # scatter, whose sustained rates differ by 20-50x on XLA-CPU);
-        # ``bytes_moved`` charges one gather-rate pass otherwise. A
-        # substrate-targeted declaration (``detail["substrate_memory"]``,
-        # keyed by kind) beats both: the Pallas kernels' sweeps depend on
-        # the grain axis (x replicated per program, per-block partials), so
-        # this is the term that makes predictions rank block sizes.
+        # ``bytes_moved`` charges one gather-rate pass otherwise.
         per_launch = estimate.detail.get("memory_bytes_per_launch")
         access = estimate.detail.get("memory_access", "gather")
-        targeted = (estimate.detail.get("substrate_memory") or {}).get(substrate)
-        if targeted is not None:
-            per_launch = targeted.get("bytes_per_launch", per_launch)
-            access = targeted.get("access", access)
         mem_bytes = (
             max(1.0, launches) * float(per_launch)
             if per_launch is not None

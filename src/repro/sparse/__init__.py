@@ -1,5 +1,4 @@
 from .csr import CSR, spmv_csr_ref
-from .ell import ELL, ell_from_csr, spmv_ell_ref
 from .gen import (
     TABLE3_SIGNATURES,
     edges_to_csr,
@@ -11,8 +10,8 @@ from .gen import (
 from .graph import PartitionedGraph, global_id, local_slot, owner_of, partition_graph
 
 __all__ = [
-    "CSR", "ELL", "PartitionedGraph", "TABLE3_SIGNATURES",
-    "edges_to_csr", "ell_from_csr", "erdos_renyi_edges", "global_id",
+    "CSR", "PartitionedGraph", "TABLE3_SIGNATURES",
+    "edges_to_csr", "erdos_renyi_edges", "global_id",
     "laplacian_2d", "local_slot", "owner_of", "partition_graph",
-    "rmat_edges", "skewed_matrix", "spmv_csr_ref", "spmv_ell_ref",
+    "rmat_edges", "skewed_matrix", "spmv_csr_ref",
 ]

@@ -31,16 +31,70 @@ def _ref_bfs_levels(adj_csr, root):
     return level
 
 
-@pytest.mark.parametrize("gen,scale", [("er", 8), ("rmat", 8)])
-def test_bfs_matches_reference_reachability(gen, scale):
-    n = 1 << scale
-    edges = erdos_renyi_edges(scale, 8, seed=0) if gen == "er" else rmat_edges(scale, 8, seed=0)
-    g = edges_to_csr(edges, n)
+def _random_digraph(n: int, k: int, seed: int):
+    """A directed graph of ``n`` vertices, each with ``k`` random out-edge
+    slots of which about one in ``n + 1`` is empty (duplicates and self loops
+    dropped)."""
+    adj = np.random.default_rng(seed).integers(-1, n, size=(n, k))
+    src = np.repeat(np.arange(n), k)
+    keep = adj.reshape(-1) >= 0
+    return edges_to_csr(np.stack([src[keep], adj.reshape(-1)[keep]], 1), n, symmetrize=False)
+
+
+BFS_GRAPHS = {
+    "er": lambda: edges_to_csr(erdos_renyi_edges(8, 8, seed=0), 256),
+    "rmat": lambda: edges_to_csr(rmat_edges(8, 8, seed=0), 256),
+    "er_ef6": lambda: edges_to_csr(erdos_renyi_edges(8, 6, seed=5), 256),
+    # directed; 100 and 37 vertices leave padding vertices on the nodelets,
+    # and one out-edge a vertex makes a traversal a chain of rounds
+    **{
+        f"digraph{n}x{k}": (lambda n=n, k=k: _random_digraph(n, k, seed=n * k))
+        for n, k in ((64, 4), (100, 6), (256, 1), (37, 8))
+    },
+    # vertex 0 points at the other 44 and nothing points back: from 0 one
+    # round reaches every vertex, from a leaf none
+    "star45": lambda: edges_to_csr(
+        np.stack([np.zeros(44, int), np.arange(1, 45)], 1), 45, symmetrize=False
+    ),
+}
+COMMS = {"migrate": Comm.MIGRATE, "remote_write": Comm.REMOTE_WRITE}
+REACHABILITY_CASES = [
+    # the first two under the default strategy (remote write)
+    pytest.param("er", 0, "remote_write", None, id="er-8"),
+    pytest.param("rmat", 0, "remote_write", None, id="rmat-8"),
+    pytest.param("er", 0, "migrate", None, id="er-8-migrate"),
+    pytest.param("rmat", 0, "migrate", None, id="rmat-8-migrate"),
+    *(
+        pytest.param(graph, root, comm, max_rounds, id=f"{graph}-{root}-{comm}{tag}")
+        for graph, roots, max_rounds, tag in (
+            ("er_ef6", (0, 3, 200), None, ""),
+            *((name, (0,), None, "") for name in BFS_GRAPHS if name.startswith("digraph")),
+            ("star45", (0, 7), None, ""),
+            # stopped before the frontier empties: depths 4 and 8
+            ("er_ef6", (0,), 2, "-2rounds"),
+            ("digraph256x1", (0,), 3, "-3rounds"),
+        )
+        for root in roots
+        for comm in COMMS
+    ),
+]
+
+
+@pytest.mark.parametrize("graph,root,comm,max_rounds", REACHABILITY_CASES)
+def test_bfs_matches_reference_reachability(graph, root, comm, max_rounds):
+    """The served BFS under each S2 strategy against a host BFS: the same
+    vertices reached (those within ``max_rounds`` levels, where it is set),
+    each parent an edge of a valid tree."""
+    g = BFS_GRAPHS[graph]()
     pg = partition_graph(g, 8)
-    parents = np.asarray(bfs(pg, 0))
-    ref_level = _ref_bfs_levels(g, 0)
+    parents = np.asarray(
+        bfs(pg, root, MigratoryStrategy(comm=COMMS[comm]), max_rounds=max_rounds)
+    )
+    ref_level = _ref_bfs_levels(g, root)
+    if max_rounds is not None:
+        ref_level[ref_level > max_rounds] = -1
     assert ((parents >= 0) == (ref_level >= 0)).all()
-    assert validate_parents(pg, 0, parents)
+    assert validate_parents(pg, root, parents)
 
 
 def test_bfs_parent_levels_are_minimal():

@@ -33,8 +33,6 @@ from repro.engine import (
     BFSOp,
     GSANAInputs,
     GSANAOp,
-    OpNotSupportedError,
-    PallasSubstrate,
     RunReport,
     SpMVInputs,
     SpMVOp,
@@ -148,18 +146,7 @@ def test_run_by_op_name(spmv_problem):
     assert report.op == "spmv" and report.substrate == "local"
 
 
-# -- pallas substrate ----------------------------------------------------------
-
-
-def test_spmv_pallas_matches_local(spmv_problem):
-    a, inputs = spmv_problem
-    st = MigratoryStrategy()
-    y_local, _ = run(SpMVOp(), inputs, st, "local")
-    y_pallas, report = run(SpMVOp(), inputs, st, "pallas")
-    np.testing.assert_allclose(
-        np.asarray(y_local), np.asarray(y_pallas), atol=1e-4
-    )
-    assert report.substrate == "pallas"
+# -- pallas substrate (GSANA through kernels/topk_sim) -----------------------
 
 
 def test_gsana_pallas_matches_local(gsana_problem):
@@ -170,19 +157,6 @@ def test_gsana_pallas_matches_local(gsana_problem):
     np.testing.assert_allclose(
         np.asarray(s_l)[fin], np.asarray(s_p)[fin], atol=1e-5
     )
-
-
-def test_bfs_pallas_matches_local(bfs_problem):
-    """("bfs", "pallas") resolves now and its parent tree is bit-identical
-    to the local oracle (integer min-scatter is deterministic)."""
-    assert PallasSubstrate().supports("bfs")
-    assert PallasSubstrate().supports("spmv")
-    with pytest.raises(OpNotSupportedError):
-        PallasSubstrate().kernel("moe_dispatch")
-    p_local, _ = run(BFSOp(), bfs_problem, MigratoryStrategy(), "local")
-    p_pallas, report = run(BFSOp(), bfs_problem, MigratoryStrategy(), "pallas")
-    np.testing.assert_array_equal(np.asarray(p_local), np.asarray(p_pallas))
-    assert report.substrate == "pallas"
 
 
 # -- registry + report schema --------------------------------------------------

@@ -25,7 +25,6 @@ from repro.engine import (
     SpMVInputs,
     capabilities,
     candidate_grid,
-    default_registry,
     get_substrate,
     list_substrates,
     run,
@@ -57,7 +56,7 @@ def test_every_pair_resolves_or_raises_cleanly(op_name, sub_name):
 
 def test_capabilities_table_shape():
     """Rows = every registered op, columns = every registered substrate; the
-    known support facts hold (pallas runs spmv/bfs/gsana but not moe).
+    known support facts hold (pallas runs gsana only).
     Compared over the three core substrates — importing ``repro.cluster``
     anywhere in the session legitimately adds a ``cluster`` column (its
     cells mirror the workers' kind, ``local`` when no cluster is active)."""
@@ -69,8 +68,9 @@ def test_capabilities_table_shape():
     def core(op_name):
         return {k: table[op_name][k] for k in ("local", "mesh", "pallas")}
 
-    assert core("spmv") == {"local": True, "mesh": True, "pallas": True}
-    assert core("bfs") == {"local": True, "mesh": True, "pallas": True}
+    assert core("spmv") == {"local": True, "mesh": True, "pallas": False}
+    assert core("bfs") == {"local": True, "mesh": True, "pallas": False}
+    assert core("gsana") == {"local": True, "mesh": True, "pallas": True}
     assert core("moe_dispatch") == {"local": True, "mesh": True, "pallas": False}
     if "cluster" in list_substrates():
         assert table["spmv"]["cluster"] is True  # workers serve local kernels
@@ -122,29 +122,6 @@ def test_opspec_grid_drives_autotuner():
     moe = candidate_grid("moe_dispatch")
     assert len(moe) == 2
     assert {st.comm for st in moe} == {Comm.MIGRATE, Comm.REMOTE_WRITE}
-
-
-def test_opspec_grid_is_substrate_aware():
-    """Targeting the grid at pallas widens the kernel-tuning axis to the
-    Pallas block_rows candidates; other substrates (and None) see the
-    substrate-blind grid; zero-arg grid callables still work."""
-    from repro.engine import PALLAS_BLOCK_CANDIDATES
-
-    spmv_p = candidate_grid("spmv", "pallas")
-    assert len(spmv_p) == 2 * 2 * 2 * 2 * len(PALLAS_BLOCK_CANDIDATES)
-    assert {st.grain for st in spmv_p} == set(PALLAS_BLOCK_CANDIDATES)
-    bfs_p = candidate_grid("bfs", "pallas")
-    assert {st.grain for st in bfs_p} == set(PALLAS_BLOCK_CANDIDATES)
-    # substrate-blind spellings agree, instance or name alike
-    assert candidate_grid("spmv", "local") == candidate_grid("spmv")
-    assert candidate_grid("bfs", get_substrate("mesh")) == candidate_grid("bfs")
-    # a zero-arg grid registered by an out-of-tree op is called as before
-    # (kernel registered too so the drift check never sees an unservable op)
-    reg = default_registry()
-    spec = OpSpec(name="zero_arg_grid_op", factory=object, grid=lambda: [MigratoryStrategy()])
-    reg.register_op(spec, replace=True)
-    reg.register_kernel("zero_arg_grid_op", "local", lambda sub: None, replace=True)
-    assert candidate_grid("zero_arg_grid_op", "pallas") == [MigratoryStrategy()]
 
 
 def test_opspec_cost_model_registered_into_core():
@@ -242,7 +219,7 @@ def test_renamed_subclass_inherits_parent_kernels():
 
     assert PinnedKind().substrate_kind == "pallas"
     assert not PinnedKind().supports("moe_dispatch")  # pallas has no moe kernel
-    assert PinnedKind().supports("bfs")  # ("bfs", "pallas") registered
+    assert PinnedKind().supports("gsana")  # ("gsana", "pallas") registered
 
 
 def test_one_device_substrates_take_one_slot_on_an_accelerator(monkeypatch):

@@ -5,8 +5,8 @@ import pytest
 from hypothesis_compat import given, settings, st
 
 from repro.sparse import (
-    CSR, ELL, edges_to_csr, ell_from_csr, erdos_renyi_edges, laplacian_2d,
-    partition_graph, rmat_edges, skewed_matrix, spmv_csr_ref, spmv_ell_ref,
+    CSR, edges_to_csr, erdos_renyi_edges, laplacian_2d,
+    partition_graph, rmat_edges, skewed_matrix, spmv_csr_ref,
 )
 
 
@@ -26,13 +26,6 @@ def test_csr_dense_roundtrip():
     d = (rng.random((13, 17)) < 0.2) * rng.standard_normal((13, 17)).astype(np.float32)
     a = CSR.from_dense(d)
     assert np.allclose(np.asarray(a.to_dense()), d)
-
-
-def test_ell_matches_csr():
-    a = laplacian_2d(6)
-    e = ell_from_csr(a)
-    x = jnp.arange(36, dtype=jnp.float32)
-    assert np.allclose(np.asarray(spmv_ell_ref(e, x)), np.asarray(spmv_csr_ref(a, x)))
 
 
 def test_generators_shapes():

@@ -9,10 +9,6 @@ memory fits; it runs nothing. Sizes follow the chip smoke (``chip_smoke.py``):
 takes ~20 s on a CPU host), GSANA n=8192 PAIR tasks, and a
 32-head x 2048 x 128 bf16 attention.
 
-The Pallas SpMV and BFS kernels do not lower for the TPU as written; the
-last tests pin that, and that the ``pallas`` substrate refuses them at plan
-time on a TPU instead of interpreting them.
-
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and test workers import every file.
 """
@@ -25,13 +21,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import MigratoryStrategy, bfs_local, spmv_local
 from repro.core.spmv import PartitionedELL
-from repro.engine import BFSInputs, BFSOp, OpNotSupportedError, SpMVInputs, SpMVOp
-from repro.engine.autotune import candidate_grid
-from repro.engine.runner import build_plan
-from repro.engine.substrate import TPU_LOWERING_REFUSALS, PallasSubstrate
-from repro.kernels.bfs.kernel import bfs_expand_pallas
 from repro.kernels.flash_attention.ops import flash_attention
-from repro.kernels.spmv.kernel import spmv_ell_pallas
 from repro.kernels.topk_sim.kernel import topk_sim_pallas
 from repro.sparse.graph import PartitionedGraph
 
@@ -215,49 +205,3 @@ def test_topk_similarity_compiles_for_v5e(shapes):
         feats, feats, mask, mask,
     )
     assert "tpu_custom_call" in compiled.as_text()
-
-
-@pytest.mark.parametrize("op", sorted(TPU_LOWERING_REFUSALS))
-def test_refused_kernels_still_do_not_lower(shapes, op):
-    """The refusal table is what the compiler says today. When a kernel
-    starts to lower, this fails: drop its row so the engine serves it."""
-    rows = 4096
-    if op == "spmv":
-        fn = lambda c, v, x: spmv_ell_pallas(c, v, x, block_rows=256, interpret=False)  # noqa: E731
-        args = (shapes((rows, 8), jnp.int32), shapes((rows, 8)), shapes((rows,)))
-    else:
-        fn = lambda a, f: bfs_expand_pallas(a, f, block_rows=256, interpret=False)  # noqa: E731
-        args = (shapes((rows, 8), jnp.int32), shapes((rows,), jnp.int32))
-    says = {"spmv": "Only 2D gather is supported", "bfs": "scatter-min"}[op]
-    assert says in TPU_LOWERING_REFUSALS[op]
-    with pytest.raises(NotImplementedError, match=says):
-        jax.jit(fn).lower(*args).compile()
-
-
-@pytest.mark.parametrize("op", sorted(TPU_LOWERING_REFUSALS))
-def test_refused_kernels_refuse_at_plan_time_on_tpu(monkeypatch, op):
-    """With the backend reading ``tpu``, the pallas substrate resolves to
-    compiled kernels and refuses the ones that do not lower — at plan time
-    and in the autotune grid — instead of interpreting them."""
-    from repro.core import partition_ell
-    from repro.sparse import edges_to_csr, erdos_renyi_edges, laplacian_2d, partition_graph
-
-    if op == "spmv":
-        inputs = SpMVInputs(partition_ell(laplacian_2d(8), 4), jnp.ones(64, jnp.float32))
-        spec = SpMVOp()
-    else:
-        inputs = BFSInputs(partition_graph(edges_to_csr(erdos_renyi_edges(6, 4), 64), 4), 0)
-        spec = BFSOp()
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    sub = PallasSubstrate()
-    assert sub.interpret is False
-    assert not sub.supports(op)
-    with pytest.raises(OpNotSupportedError, match="no TPU lowering"):
-        build_plan(spec, inputs, MigratoryStrategy(), "pallas")
-    with pytest.raises(OpNotSupportedError, match="no TPU lowering"):
-        candidate_grid(op, "pallas")
-    # gsana's top-k kernel lowers: it stays servable on the chip
-    assert sub.supports("gsana")
-    monkeypatch.undo()
-    assert PallasSubstrate().supports(op)  # off the chip it interprets
-    build_plan(spec, inputs, MigratoryStrategy(), "pallas")
