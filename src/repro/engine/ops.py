@@ -45,6 +45,7 @@ from ..core.strategies import Layout, MigratoryStrategy, TrafficStats, strategy_
 from ..sparse.graph import PartitionedGraph
 from .api import ExecutionPlan, plan_key
 from .registry import OpSpec, default_registry, register_op
+from .spans import count
 from .substrate import Substrate
 
 # grain values worth distinguishing for row-grained ops (None = dynamic);
@@ -57,12 +58,16 @@ def _spmv_grid() -> list[MigratoryStrategy]:
 
 
 # Cross-plan memo for host-side derived stats (traffic replays, placement
-# models, nnz scans). The serving path builds a fresh plan per request, so
-# ``plan.meta`` caching alone reruns the O(edges)-ish numpy work — and its
-# device->host transfers — for every served request of the same inputs,
-# serializing the executor pool on the GIL. Keyed by inputs object identity
-# + a static discriminator, validated with a weakref so a recycled id of a
-# collected object can never alias (the moe_op replay-memo pattern).
+# models, nnz scans). The serving path builds a fresh plan and usually a
+# fresh inputs object per request, so ``plan.meta`` caching alone reruns the
+# O(edges)-ish numpy work — and its device->host transfers — for every
+# served request, serializing the executor pool on the GIL. A stat is keyed
+# by the identity of its anchor, the object it is a function of (the matrix,
+# the graph: not the inputs that wrap it with a new ``x``), plus a static
+# discriminator holding everything else its compute reads. A weakref
+# validates the anchor, so a recycled id of a collected object can never
+# alias. Each lookup counts ``derived.hits`` or ``derived.misses`` under the
+# open span (:func:`.spans.count`).
 _DERIVED_MEMO: "collections.OrderedDict[tuple, tuple]" = collections.OrderedDict()
 _DERIVED_MEMO_MAX = 256
 
@@ -72,7 +77,9 @@ def _derived_cached(kind: str, anchor: Any, extra: Any, compute: Callable[[], An
     hit = _DERIVED_MEMO.get(key)
     if hit is not None and hit[0]() is anchor:
         _DERIVED_MEMO.move_to_end(key)
+        count({"derived.hits": 1})
         return hit[1]
+    count({"derived.misses": 1})
     value = compute()
     try:
         _DERIVED_MEMO[key] = (weakref.ref(anchor), value)
@@ -114,17 +121,16 @@ class SpMVOp:
         )
 
     def traffic(self, plan: ExecutionPlan) -> TrafficStats:
-        inputs, strategy = plan.inputs, plan.strategy
+        a, strategy = plan.inputs.a, plan.strategy
         return _derived_cached(
-            "spmv_traffic", inputs, strategy.cache_key(),
-            lambda: spmv_traffic(inputs.a, strategy),
+            "spmv_traffic", a, strategy.cache_key(),
+            lambda: spmv_traffic(a, strategy),
         )
 
     def bytes_moved(self, plan: ExecutionPlan) -> int:
-        inputs, n_cols = plan.inputs, plan.meta["n_cols"]
+        a, n_cols = plan.inputs.a, plan.meta["n_cols"]
         return _derived_cached(
-            "spmv_bytes", inputs, n_cols,
-            lambda: spmv_bytes_moved(inputs.a, n_cols),
+            "spmv_bytes", a, n_cols, lambda: spmv_bytes_moved(a, n_cols),
         )
 
     def metrics(self, plan: ExecutionPlan, result: Any, seconds: float) -> dict[str, Any]:
@@ -174,13 +180,14 @@ class BFSOp:
 
     def _stats(self, plan: ExecutionPlan):
         """The numpy traffic replay: O(edges), computed once per
-        (inputs, root, strategy) and shared across every plan built for
-        them (the serving path builds one plan per request)."""
+        (graph, root, strategy) and shared across every plan and inputs
+        object built for them (the serving path builds one of each per
+        request)."""
         if "run_stats" not in plan.meta:
-            inputs, strategy = plan.inputs, plan.strategy
+            g, root, strategy = plan.inputs.g, plan.inputs.root, plan.strategy
             plan.meta["run_stats"] = _derived_cached(
-                "bfs_replay", inputs, (inputs.root, strategy.cache_key()),
-                lambda: bfs_traffic(inputs.g, inputs.root, strategy),
+                "bfs_replay", g, (root, strategy.cache_key()),
+                lambda: bfs_traffic(g, root, strategy),
             )
         return plan.meta["run_stats"]
 

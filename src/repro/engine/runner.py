@@ -16,7 +16,8 @@ The stages are individually exposed (DESIGN.md §1):
 Each call opens the spans ``engine.dispatch`` (until the executor returns
 unready arrays) and ``engine.device`` (``block_until_ready``), and the
 report's derived stats run under ``engine.derived`` (:mod:`.spans`), which
-also adds the op's per-request counters (``SpMVOp.counters``) to the
+also adds the op's per-request counters (``SpMVOp.counters``, and the
+derived-stats memo's ``derived.hits`` / ``derived.misses``) to the
 request's span totals.
 """
 from __future__ import annotations

@@ -320,8 +320,10 @@ class ServiceStats:
       these spans: queue wait is admission -> ``engine.run`` start, service
       time ``engine.run`` start -> ``engine.resolve`` start.
     - ``counters`` — per-request counts the ops add under ``engine.derived``,
-      summed: ``spmv.slots`` (padded index slots gathered) and
-      ``spmv.pieces`` (ELL rows added by splitting long rows).
+      summed: ``spmv.slots`` (padded index slots gathered),
+      ``spmv.pieces`` (ELL rows added by splitting long rows), and
+      ``derived.hits`` / ``derived.misses`` (lookups of the derived-stats
+      memo that found, or had to compute, a stat).
     """
 
     requests: int = 0

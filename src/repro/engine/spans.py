@@ -16,7 +16,8 @@ span costs a few microseconds, against a served call of milliseconds.
 
 Counters (:func:`count`) add whole numbers to the same totals, under the
 innermost open span's owner: ``spmv.slots`` and ``spmv.pieces`` per served
-SpMV, from its plan's layout.
+SpMV, from its plan's layout, and ``derived.hits`` / ``derived.misses`` per
+lookup of the ops' derived-stats memo.
 
 XLA compiles (JAX's ``/jax/core/compile/backend_compile_duration`` event,
 persistent-cache reads included) are counted in the same totals, under the

@@ -26,6 +26,7 @@ from repro.core import (
     plan_stats,
     layout_hcb,
     round_up,
+    spmv_bytes_moved,
     spmv_traffic,
 )
 from repro.engine import (
@@ -124,6 +125,70 @@ def test_bfs_local_matches_legacy_traffic(bfs_problem, comm):
     assert report.metrics["rounds"] == legacy.rounds
     assert report.metrics["edges_traversed"] == legacy.edges_traversed
     assert report.metrics["reached"] == int((np.asarray(parents) >= 0).sum())
+
+
+# -- derived-stats memo: keyed on the data a stat reads --------------------------
+
+
+def _counting(monkeypatch, name: str) -> list:
+    """Wrap ``repro.engine.ops.<name>`` so each call appends to the list."""
+    import repro.engine.ops as ops_mod
+
+    calls, fn = [], getattr(ops_mod, name)
+
+    def wrapper(*args, **kwargs):
+        calls.append(args)
+        return fn(*args, **kwargs)
+
+    monkeypatch.setattr(ops_mod, name, wrapper)
+    return calls
+
+
+SPMV_STATS = {
+    "traffic_replicated_x": ("spmv_traffic", MigratoryStrategy(replicate_x=True)),
+    "traffic_striped_x": ("spmv_traffic", MigratoryStrategy(replicate_x=False)),
+    "bytes_moved": ("spmv_bytes_moved", MigratoryStrategy()),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SPMV_STATS))
+def test_spmv_derived_stats_are_keyed_on_the_matrix(monkeypatch, case):
+    """Two ``SpMVInputs`` over one matrix with different ``x`` compute a
+    stat once; a second matrix of the same shape computes it again."""
+    name, st = SPMV_STATS[case]
+    calls = _counting(monkeypatch, name)
+    op, sub = SpMVOp(), get_substrate("local")
+
+    def stat(a, x):
+        plan = op.plan(SpMVInputs(a, x), st, sub)
+        return op.traffic(plan) if name == "spmv_traffic" else op.bytes_moved(plan)
+
+    a, b = (partition_ell(laplacian_2d(11), 8) for _ in range(2))
+    x = np.random.default_rng(1).standard_normal(121).astype(np.float32)
+    first = stat(a, jnp.asarray(x))
+    assert stat(a, jnp.asarray(2 * x)) == first and len(calls) == 1
+    expected = spmv_traffic(a, st) if name == "spmv_traffic" else spmv_bytes_moved(a, 121)
+    assert first == expected
+    assert stat(b, jnp.asarray(x)) == expected and len(calls) == 2
+
+
+def test_bfs_replay_is_keyed_on_the_graph_and_root(monkeypatch):
+    """One graph at one root replays once, whatever inputs object (or
+    ``max_rounds``, which the replay does not read) wraps it; another root
+    replays again."""
+    calls = _counting(monkeypatch, "bfs_traffic")
+    op, sub, st = BFSOp(), get_substrate("local"), MigratoryStrategy()
+    g = partition_graph(edges_to_csr(erdos_renyi_edges(8, 6, seed=5), 256), 8)
+
+    def replay(inputs):
+        return op._stats(op.plan(inputs, st, sub))
+
+    first = replay(BFSInputs(g, 3))
+    assert replay(BFSInputs(g, 3)) is first
+    assert replay(BFSInputs(g, 3, max_rounds=2)) is first and len(calls) == 1
+    assert first == bfs_traffic(g, 3, st)
+    other = replay(BFSInputs(g, 7))
+    assert len(calls) == 2 and other == bfs_traffic(g, 7, st)
 
 
 def test_gsana_local_matches_legacy_plan_stats(gsana_problem):

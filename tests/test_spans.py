@@ -110,6 +110,21 @@ def test_span_counts_in_batch_mode():
     assert counts[COMPILE] == 1 and counts[SCHEDULE] == 1
 
 
+def test_derived_stats_are_computed_once_per_matrix():
+    """Each request wraps the one matrix in a new ``SpMVInputs``: the memo
+    misses once per derived stat (traffic, bytes, layout) on the first
+    request and hits on every later one."""
+    first = _inputs(9)
+    requests, stats_per_request = 5, 3
+    with EngineService(cache=PlanCache()) as svc:
+        for i in range(requests):
+            x = first.x if i == 0 else jnp.full_like(first.x, i)
+            svc.submit(Request("spmv", SpMVInputs(first.a, x))).result(timeout=300)
+        counters = svc.stats().counters
+    assert counters["derived.misses"] == stats_per_request
+    assert counters["derived.hits"] == (requests - 1) * stats_per_request
+
+
 def test_compiles_are_counted_under_the_compile_stage_not_the_warm_path():
     inputs = _inputs(13)  # a shape no other test compiles
     with EngineService(cache=PlanCache()) as svc:
