@@ -1,8 +1,9 @@
 """Mean milliseconds of the program's ``engine.derived`` span over the
 requests the timed service served (the window): the op's traffic model,
-bytes moved, metrics and prediction after each product, for SpMV a scan of
-the ``cols`` plane of each new inputs object. Read from the service's span
-totals; None for a program without the span."""
+bytes moved, metrics and prediction after each product, and for SpMV the
+lookups of the stats it keeps per matrix (no scan of ``cols`` once a
+matrix has been seen). Read from the service's span totals; None for a
+program without the span."""
 
 
 def read(run):

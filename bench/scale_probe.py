@@ -24,19 +24,21 @@ from pathlib import Path
 import numpy as np
 
 ROOT = Path(__file__).resolve().parents[1]
-sys.path.insert(0, str(ROOT / "src"))
-sys.path.insert(0, str(ROOT / "bench"))
+sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-import gen  # noqa: E402
+from bench import gen  # noqa: E402
+from bench.harness import read_json  # noqa: E402
+from bench.kinds import kron_bfs  # noqa: E402
 from repro.core.spmv import partition_ell  # noqa: E402
 from repro.engine import BFSInputs, BFSOp, EngineService, Request, SpMVInputs, SpMVOp  # noqa: E402
 from repro.sparse.csr import CSR  # noqa: E402
-from repro.sparse.graph import partition_graph  # noqa: E402
 
-K_CAP = {12: 1536, 13: 2560, 14: 4096, 15: 6656}
+# the padded adjacency width tried at each scale: a multiple of 128 above
+# the hub's degree; scale 15 is the cell's own configuration
+K_CAP = {12: 1536, 13: 2560, 14: 4096}
 
 
 def emit(**row):
@@ -48,13 +50,12 @@ def program_csr(h: gen.HostCSR) -> CSR:
 
 
 def kron_graph(scale: int, seed: int, roots: int):
-    rng = np.random.default_rng(seed)
-    n = 1 << scale
-    edges = gen.kronecker_edges(rng, scale, 16, 0.57, 0.19, 0.19)
-    deg = gen.undirected_csr(edges, n).degrees()
-    keys = rng.choice(np.flatnonzero(deg > 0), size=roots, replace=False)
-    host = gen.undirected_csr(gen.relabel(edges, n, rng, keys), n)
-    return host, partition_graph(program_csr(host), 8, k=K_CAP[scale])
+    """The BFS cell's graph (``bench/kinds/kron_bfs.py``) at ``scale``,
+    drawn from ``seed`` with ``roots`` keys, and its layout."""
+    config = read_json(ROOT / "bench" / "configs" / "graph500_kron.json")
+    config.update(scale=scale, graph_seed=seed, keys=roots, k=K_CAP.get(scale, config["k"]))
+    host = kron_bfs.kron_graph(config, seed)
+    return host, kron_bfs.layout(host, config)
 
 
 def served(service, request, host_fetch=True) -> float:

@@ -3,8 +3,9 @@
 - The control (each kind's ``control``: the reference one step below the
   configuration's guarantee, in the program's place) fails a limit.
 - A whole run with the timed path broken underneath (an answer altered
-  where it is produced, or never produced) reads ``correct: false``; the
-  same run unbroken reads ``true``.
+  where it is produced, a search whose rounds leave their state unchanged,
+  or an answer never produced) reads ``correct: false``; the same run
+  unbroken reads ``true``.
 
 Both drive the harness past its look for a chip.
 
@@ -17,6 +18,7 @@ import json
 import time
 from pathlib import Path
 
+import jax.numpy as jnp
 import pytest
 
 import repro.engine.service as service_module
@@ -26,10 +28,15 @@ from bench.control import readings
 ROOT = Path(__file__).resolve().parents[2]
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
 CELLS = {w["name"]: w for w in SPEC["workloads"]}
-TINY = {"laplacian2d": {"grid_side": 32}}
+TINY = {
+    "laplacian2d": {"grid_side": 32},
+    "table3_stanford": {"rows": 4000, "longest_row": 900},
+    "graph500_kron": {"scale": 8, "k": 256},
+}
 SEED = 2**31 + 99
 # the package re-exports functions under these names; take the modules
 core_spmv = importlib.import_module("repro.core.spmv")
+core_bfs = importlib.import_module("repro.core.bfs")
 
 
 def _run(name: str) -> dict:
@@ -38,7 +45,7 @@ def _run(name: str) -> dict:
                             TINY[cell["config"]])
 
 
-@pytest.mark.parametrize("name", ["spmv.lap.solver"])
+@pytest.mark.parametrize("name", ["spmv.lap.solver", "bfs.kron"])
 def test_control_fails_a_limit_and_the_program_does_not(name):
     cell = CELLS[name]
     row = readings(cell, SEED, 0.5, True, TINY[cell["config"]])
@@ -59,15 +66,30 @@ def _alter_y(original):
     return broken
 
 
+def _alter_parent(original):
+    def broken(adj, root, max_rounds):
+        return original(adj, root, max_rounds).at[0].add(1)
+    return broken
+
+
+def _rounds_change_nothing(original):
+    def broken(adj, root, max_rounds):
+        n = adj.shape[0]
+        return jnp.full((n,), core_bfs.UNVISITED, jnp.int32).at[root].set(root)
+    return broken
+
+
 @pytest.mark.parametrize("name,module,attr,fault", [
     ("spmv.lap.solver", core_spmv, "_spmv_local", _alter_y),
+    ("bfs.kron", core_bfs, "_bfs_local", _alter_parent),
+    ("bfs.kron", core_bfs, "_bfs_local", _rounds_change_nothing),
 ])
 def test_an_altered_answer_is_not_correct(monkeypatch, name, module, attr, fault):
     monkeypatch.setattr(module, attr, fault(getattr(module, attr)))
     assert _run(name)["correct"] is False
 
 
-@pytest.mark.parametrize("name", ["spmv.lap.solver"])
+@pytest.mark.parametrize("name", ["spmv.lap.solver", "bfs.kron"])
 def test_an_answer_that_never_comes_is_not_correct(monkeypatch, name):
     original = service_module.single_call
     calls = {"n": 0}
